@@ -64,6 +64,9 @@ def main() -> None:
     args = ap.parse_args()
     scale = 2 if args.full else 1
 
+    from repro.engine.programs import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_algorithms, bench_backends, bench_cluster,
                             bench_correlation, bench_engine,
                             bench_error_curves, bench_kernels, bench_quant,

@@ -340,14 +340,10 @@ def find_medoids_ragged(data, lengths=None,
     if cfg.algo != "corr_sh":
         raise ValueError(f"ragged mode requires algo='corr_sh', "
                          f"got {cfg.algo!r}")
-    donate = False
     if isinstance(data, (list, tuple)):
         if lengths is not None:
             raise ValueError("pass lengths only with pre-packed array data")
         data, lengths = pack_queries(list(data), min_bucket=cfg.min_bucket)
-        # the facade packed this buffer itself and never touches it again —
-        # donate it to the program. User-passed arrays are never donated.
-        donate = True
     elif lengths is None:
         raise ValueError("pre-packed array data needs explicit lengths")
     data = jnp.asarray(data)
@@ -355,13 +351,10 @@ def find_medoids_ragged(data, lengths=None,
     from repro.core.bucketing import bucket_n
     n_bucket = bucket_n(n_bucket, cfg.min_bucket)
     key = _key_of(key, cfg)
-    # A quantized run may need the buffer again for the fp32 fallback, so
-    # only the fallback dispatch (the buffer's last use) may take it.
     out = ragged_medoids(data, lengths, key,
                          budget=cfg.budget_per_arm * n_bucket,
                          metric=cfg.metric, backend=cfg.backend,
                          min_bucket=cfg.min_bucket,
-                         donate=donate and cfg.precision == "fp32",
                          telemetry=cfg.telemetry, precision=cfg.precision,
                          error_model=cfg.quant_error_model)
     tel = None
@@ -379,7 +372,7 @@ def find_medoids_ragged(data, lengths=None,
             fout = ragged_medoids(data, lengths, key,
                                   budget=cfg.budget_per_arm * n_bucket,
                                   metric=cfg.metric, backend=cfg.backend,
-                                  min_bucket=cfg.min_bucket, donate=donate,
+                                  min_bucket=cfg.min_bucket,
                                   telemetry=False)
             medoids = jnp.where(verified, medoids, fout)
     if tel is not None:

@@ -61,7 +61,6 @@ from repro.core.corr_sh import _medoid_impl, ragged_medoids
 from repro.deprecation import warn_once
 from repro.engine import (HalvingProblem, build_delta, run_halving,
                           swap_delta)
-from repro.engine.programs import donation_enabled
 
 # refiner hook: (cluster member arrays, key) -> (local medoid indices, pulls).
 # The default runs bucketed ragged dispatches in-process; the service layer
@@ -233,8 +232,7 @@ def _swap_sweep_impl(data: jnp.ndarray, dmat: jnp.ndarray, meds: jnp.ndarray,
     """The whole SWAP phase as ONE device-resident program.
 
     A ``lax.scan`` over ``max_rounds`` candidate sweeps carrying the
-    ``(n, k)`` assignment cache ``dmat`` (donated — the caller's copy is
-    consumed), the medoid slots, and the accept/reject state machine of the
+    ``(n, k)`` assignment cache ``dmat``, the medoid slots, and the accept/reject state machine of the
     host loop it replaces: a round's bandit winner is verified against the
     exact incumbent-delta vector on device, an accepted swap rewrites one
     cache column and resets the rejection counter, and two consecutive
@@ -285,13 +283,9 @@ def _swap_sweep_impl(data: jnp.ndarray, dmat: jnp.ndarray, meds: jnp.ndarray,
     return meds, nearest, jnp.sum(d1), swaps, executed
 
 
-# The (n, k) cache is donated into the sweep where donation is real (the
-# caller's copy is dead after the phase); on CPU jax ignores donations with a
-# warning, so the flag is folded away there — same program either way.
 _swap_sweep = jax.jit(
     _swap_sweep_impl,
-    static_argnames=("max_rounds", "k", "budget", "metric", "backend"),
-    donate_argnums=(1,) if donation_enabled() else ())
+    static_argnames=("max_rounds", "k", "budget", "metric", "backend"))
 
 
 # --------------------------------------------------------------------------

@@ -41,8 +41,9 @@ backends:
     generic sort, with bit-identical stable-tie semantics — no step of a
     round leaves the chip.
 
-On non-TPU hosts the Pallas backends transparently run in interpret mode
-(see :mod:`repro.kernels.ops`), so every backend is selectable everywhere.
+The Pallas backends compile their kernels on a TPU and interpret them on
+the CPU backend (tests, small runs); on any other backend they raise (see
+:func:`repro.kernels.ops.interpret_mode`).
 """
 from __future__ import annotations
 

@@ -13,8 +13,7 @@ medoid entry points as thin adapters over it:
   and the clustering refiners dispatch to. Since PR 6 these are thin
   wrappers over the cached jitted programs of
   :mod:`repro.engine.programs` — keyed by (bucket, schedule config,
-  backend), so repeated same-shape calls never retrace, with optional arm
-  buffer donation for callers that own their packed buffers;
+  backend), so repeated same-shape calls never retrace;
 * :func:`corr_sh_medoid`, :func:`corr_sh_medoid_batch`,
   :func:`corr_sh_medoid_ragged` — the pre-facade public names, kept
   signature-compatible as deprecated shims (one ``DeprecationWarning`` per
@@ -94,7 +93,7 @@ def correlated_sequential_halving(
 
 def _medoid_impl(data: jnp.ndarray, key: jax.Array, *, budget: int,
                  metric: str = "l2", backend: str = "reference",
-                 donate: bool = False, telemetry: bool = False,
+                 telemetry: bool = False,
                  precision: str = "fp32", error_model: str = "probe"):
     """Single-query medoid (the facade's ``find_medoid`` kernel): dispatch
     the cached jitted program for this (budget, metric, backend) config.
@@ -104,15 +103,14 @@ def _medoid_impl(data: jnp.ndarray, key: jax.Array, *, budget: int,
     ``verified`` certificate right after the index."""
     instrument.note_dispatch("medoid")
     fn = programs.medoid_program(budget=budget, metric=metric,
-                                 backend=backend, donate=donate,
-                                 telemetry=telemetry, precision=precision,
+                                 backend=backend, telemetry=telemetry, precision=precision,
                                  error_model=error_model)
     return fn(data, key)
 
 
 def _batch_impl(data: jnp.ndarray, key: jax.Array, *, budget: int,
                 metric: str = "l2", backend: str = "reference",
-                donate: bool = False, telemetry: bool = False,
+                telemetry: bool = False,
                 precision: str = "fp32", error_model: str = "probe"):
     """Batched multi-query medoid: ``data (B, n, d) -> (B,)`` indices
     (``((B,), telemetry)`` with ``telemetry``).
@@ -128,8 +126,7 @@ def _batch_impl(data: jnp.ndarray, key: jax.Array, *, budget: int,
         raise ValueError(f"expected (B, n, d) batch, got shape {data.shape}")
     instrument.note_dispatch("batch")
     fn = programs.batch_program(budget=budget, metric=metric,
-                                backend=backend, donate=donate,
-                                telemetry=telemetry, precision=precision,
+                                backend=backend, telemetry=telemetry, precision=precision,
                                 error_model=error_model)
     return fn(data, key)
 
@@ -152,7 +149,7 @@ def ragged_medoids(data: jnp.ndarray, lengths, key: jax.Array, *,
                    budget: int, metric: str = "l2",
                    backend: str = "reference",
                    min_bucket: int = DEFAULT_MIN_BUCKET,
-                   donate: bool = False, telemetry: bool = False,
+                   telemetry: bool = False,
                    precision: str = "fp32", error_model: str = "probe"):
     """Ragged multi-query medoid: ``data (B, n_max, d)`` + per-query
     ``lengths (B,)`` -> ``(B,)`` medoid indices (each < its query's length);
@@ -169,9 +166,6 @@ def ragged_medoids(data: jnp.ndarray, lengths, key: jax.Array, *,
 
     Raises ``ValueError`` on an all-padding query (``length < 1``) or a
     length exceeding ``n_max`` — rejected at admission, before any dispatch.
-    ``donate=True`` donates the (bucket-padded) arm buffer to the program —
-    only for callers that own the packed buffer and never reuse it (the
-    facade and the medoid server set it for buffers they packed themselves).
     """
     if data.ndim != 3:
         raise ValueError(f"expected (B, n_max, d) batch, got shape {data.shape}")
@@ -199,8 +193,7 @@ def ragged_medoids(data: jnp.ndarray, lengths, key: jax.Array, *,
     instrument.note_dispatch("ragged")
     fn = programs.ragged_program(n_bucket=n_bucket, budget=budget,
                                  metric=metric, backend=backend,
-                                 donate=donate, telemetry=telemetry,
-                                 precision=precision,
+                                 telemetry=telemetry, precision=precision,
                                  error_model=error_model)
     return fn(data, lengths, key)
 

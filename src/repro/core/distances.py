@@ -25,10 +25,13 @@ METRICS = ("l1", "l2", "sql2", "cosine")
 
 
 def _gram(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
-    # (C, d) @ (d, R) in f32 accumulation — MXU path on TPU.
+    # (C, d) @ (d, R) in f32 accumulation — MXU path on TPU. HIGHEST keeps
+    # the multiplies f32 there too (the TPU default rounds f32 operands to
+    # bf16), so the reference and the exact oracle are fp32 on every backend.
     return jax.lax.dot_general(
         x, y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 

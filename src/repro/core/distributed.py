@@ -35,16 +35,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.backend import get_backend
 from repro.engine import default_select, round_schedule
 
-try:
-    # jax >= 0.6: shard_map is a public API and the replication check is
-    # spelled check_vma.
-    shard_map = functools.partial(jax.shard_map, check_vma=False)
-except AttributeError:
-    # jax 0.4/0.5: experimental module, check_rep spelling. Outputs are
-    # replicated via psum/all_gather either way, so the check is off.
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    shard_map = functools.partial(_experimental_shard_map, check_rep=False)
+# Outputs are replicated via psum/all_gather, so the replication check is off.
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def _gather_rows(x_local: jnp.ndarray, global_idx: jnp.ndarray,

@@ -47,8 +47,7 @@ snapshots of the four pre-refactor loops (``tests/_legacy_loops.py``):
 The loop is a pure array program with static shapes only — safe under
 ``jax.vmap`` (the batched and ragged engines map it over a leading batch
 axis) and under ``jax.jit``; :mod:`repro.engine.programs` provides the
-cached jitted entry points (with buffer donation) everything dispatches
-through.
+cached jitted entry points everything dispatches through.
 """
 from __future__ import annotations
 

@@ -5,29 +5,29 @@ factory returns a jitted callable closed over its static configuration
 (``budget``/``metric``/``backend``/bucket), memoized in a module-level
 table — so the facade (:mod:`repro.api`), the serving layer, and the
 clustering pipeline all share literally the same compiled programs, keyed by
-``(kind, schedule config, backend, donation, telemetry)`` plus jax's own
+``(kind, schedule config, backend, telemetry)`` plus jax's own
 shape key. Repeated same-shape calls never retrace (asserted counter-based
 in ``tests/test_oneprogram.py`` via :mod:`repro.engine.instrument`); a
 telemetry-carrying variant is its own cached program (more outputs), so
 turning telemetry on costs one extra trace per signature — once — and
 nothing per call thereafter.
 
-**Buffer donation**: pass ``donate=True`` to donate the arm buffer
-(argument 0) to the program — correct only when the caller owns the buffer
-and never touches it again (the facade enables it for buffers *it* packed;
-user-passed arrays are never donated). On backends without donation support
-(CPU) the flag is folded away so a donating and non-donating caller share
-one program instead of compiling twice; :func:`donation_enabled` reports
-the effective behavior.
+**Buffer donation**: only the corpus insert/delete programs donate, because
+only their outputs have the donated buffers' shapes and can reuse them in
+place. A medoid program returns indices, so a donated arm buffer could
+never be aliased (XLA would ignore it). On CPU, where XLA ignores donation,
+the flag is folded away; :func:`donation_enabled` reports the effective
+behavior.
 
-**Persistent compile cache**: :func:`enable_persistent_cache` points jax's
-compilation cache at a directory (thresholds dropped to cache-everything),
-so a restarted server re-*traces* known buckets but never re-*compiles*
-them. The ``JAX_COMPILATION_CACHE_DIR`` env var is jax's native equivalent.
+**Persistent compile cache**: :func:`enable_compile_cache` turns on jax's
+compilation cache (``JAX_COMPILATION_CACHE_DIR`` where set, else the fixed
+``<checkout>/.jax_cache``), so a restarted server re-*traces* known buckets
+but never re-*compiles* them.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Callable
 
 import jax
@@ -47,6 +47,14 @@ def donation_enabled() -> bool:
     silently ignores donations on CPU; we fold the flag away there so the
     donating and plain paths share one compiled program)."""
     return jax.default_backend() not in ("cpu",)
+
+
+def device_info() -> dict:
+    """The device this process runs on, as jax reports it — every CLI's JSON
+    line carries it, so no result is read without its device."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(jax.devices())}
 
 
 def program_cache_info() -> dict:
@@ -83,7 +91,7 @@ def _quant_config(precision: str, error_model: str, backend: str):
 
 
 def medoid_program(*, budget: int, metric: str = "l2",
-                   backend: str = "reference", donate: bool = False,
+                   backend: str = "reference",
                    telemetry: bool = False, precision: str = "fp32",
                    error_model: str = "probe") -> Callable:
     """Jitted single-query medoid: ``(data (n, d), key) -> scalar index`` —
@@ -97,7 +105,6 @@ def medoid_program(*, budget: int, metric: str = "l2",
     (:func:`repro.quant.verify.exact_winner`) — the program returns
     ``(index, verified)`` (plus telemetry), where ``verified`` is the traced
     margin-capacity certificate."""
-    eff_donate = donate and donation_enabled()
     eff_backend, eff_err = _quant_config(precision, error_model, backend)
 
     def build():
@@ -130,14 +137,14 @@ def medoid_program(*, budget: int, metric: str = "l2",
             winner, verified = quant.exact_winner(problem, out, metric)
             return (winner, verified, out.telemetry) if telemetry \
                 else (winner, verified)
-        return jax.jit(impl, donate_argnums=(0,) if eff_donate else ())
+        return jax.jit(impl)
 
-    return _memo(("medoid", budget, metric, eff_backend, eff_donate,
-                  telemetry, precision, eff_err), build)
+    return _memo(("medoid", budget, metric, eff_backend, telemetry,
+                  precision, eff_err), build)
 
 
 def batch_program(*, budget: int, metric: str = "l2",
-                  backend: str = "reference", donate: bool = False,
+                  backend: str = "reference",
                   telemetry: bool = False, precision: str = "fp32",
                   error_model: str = "probe") -> Callable:
     """Jitted batched medoid: ``(data (B, n, d), key) -> (B,) indices`` —
@@ -151,7 +158,6 @@ def batch_program(*, budget: int, metric: str = "l2",
     return ``((B,) indices, (B,) verified[, telemetry])`` — see
     :func:`medoid_program`.
     """
-    eff_donate = donate and donation_enabled()
     eff_backend, eff_err = _quant_config(precision, error_model, backend)
 
     def build():
@@ -194,14 +200,14 @@ def batch_program(*, budget: int, metric: str = "l2",
                     else (winner, verified)
 
             return jax.vmap(one)(data, keys)
-        return jax.jit(impl, donate_argnums=(0,) if eff_donate else ())
+        return jax.jit(impl)
 
-    return _memo(("batch", budget, metric, eff_backend, eff_donate,
-                  telemetry, precision, eff_err), build)
+    return _memo(("batch", budget, metric, eff_backend, telemetry,
+                  precision, eff_err), build)
 
 
 def ragged_program(*, n_bucket: int, budget: int, metric: str = "l2",
-                   backend: str = "reference", donate: bool = False,
+                   backend: str = "reference",
                    telemetry: bool = False, precision: str = "fp32",
                    error_model: str = "probe") -> Callable:
     """Jitted ragged medoid: ``(data (B, n_bucket, d), lengths (B,), key) ->
@@ -212,7 +218,6 @@ def ragged_program(*, n_bucket: int, budget: int, metric: str = "l2",
     and reference roles both); a query filling its bucket is bit-identical
     to the single-query program. Quantized programs additionally return the
     per-query ``(B,) verified`` certificate — see :func:`medoid_program`."""
-    eff_donate = donate and donation_enabled()
     eff_backend, eff_err = _quant_config(precision, error_model, backend)
 
     def build():
@@ -258,10 +263,10 @@ def ragged_program(*, n_bucket: int, budget: int, metric: str = "l2",
                     else (winner, verified)
 
             return jax.vmap(one)(data, valid, keys)
-        return jax.jit(impl, donate_argnums=(0,) if eff_donate else ())
+        return jax.jit(impl)
 
     return _memo(("ragged", n_bucket, budget, metric, eff_backend,
-                  eff_donate, telemetry, precision, eff_err), build)
+                  telemetry, precision, eff_err), build)
 
 
 # ------------------------------ corpus programs -----------------------------
@@ -356,11 +361,8 @@ def corpus_delete_program(*, metric: str = "l2",
 
 def corpus_grow_program() -> Callable:
     """Jitted capacity doubling: ``(buf (cap, d), cent, alive) -> the same
-    triple at 2*cap``. The old buffers are donated — freed as soon as the
-    copy lands — and the new tail starts dead (+inf centrality, freelisted
+    triple at 2*cap``. The new tail starts dead (+inf centrality, freelisted
     by the host store)."""
-    eff_donate = donation_enabled()
-
     def build():
         def impl(buf: jnp.ndarray, cent: jnp.ndarray, alive: jnp.ndarray):
             instrument.note_trace("corpus")
@@ -368,10 +370,9 @@ def corpus_grow_program() -> Callable:
             return (jnp.pad(buf, ((0, cap), (0, 0))),
                     jnp.pad(cent, (0, cap), constant_values=jnp.inf),
                     jnp.pad(alive, (0, cap)))
-        return jax.jit(impl,
-                       donate_argnums=(0, 1, 2) if eff_donate else ())
+        return jax.jit(impl)
 
-    return _memo(("corpus_grow", eff_donate), build)
+    return _memo(("corpus_grow",), build)
 
 
 def corpus_gather_program() -> Callable:
@@ -391,15 +392,27 @@ def corpus_gather_program() -> Callable:
 
 # --------------------------- persistent compile cache ------------------------
 
-def enable_persistent_cache(cache_dir: str) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (created if
-    missing; thresholds dropped so every engine program is cached). A
-    restarted process pays tracing again but skips XLA compilation for every
-    program signature it has seen before — the warm-restart path the medoid
-    server's warmup route rides. Returns the absolute cache path."""
-    path = os.path.abspath(os.path.expanduser(cache_dir))
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+# <checkout>/.jax_cache: a fixed path (the path is part of the cache key, so
+# a per-process or temporary directory would never hit), listed in .gitignore.
+CHECKOUT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache for this process; returns
+    its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already
+    uses that directory and no other is set here; otherwise the cache lives
+    at :data:`CHECKOUT_CACHE_DIR`. Thresholds are dropped so every engine
+    program is cached: a restarted process re-*traces* known signatures but
+    never re-*compiles* them. Called by the entry points (CLIs, benchmark
+    runner, chip smoke), never at import time."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = CHECKOUT_CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    compilation_cache.reset_cache()   # re-read the settings on next compile
     return path

@@ -1,11 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-These handle padding to block multiples, metric plumbing (Gram trick for
-ℓ2/sqℓ2/cosine), and CPU fallback: on non-TPU backends the kernels run in
-``interpret=True`` mode (numerically identical, Python-executed) so the whole
-framework is testable on this container. ``pairwise_kernel(metric)`` returns a
-drop-in replacement for ``repro.core.distances.pairwise(metric)`` and can be
-passed to ``correlated_sequential_halving(pairwise_fn=...)``.
+These handle padding to block multiples and metric plumbing (Gram trick for
+ℓ2/sqℓ2/cosine). The kernels compile to Mosaic on a TPU. On the CPU backend
+they run in Pallas interpret mode (same kernel code, executed by XLA:CPU), so
+tests and small runs work without a chip; any other backend raises instead of
+interpreting (see :func:`interpret_mode`). ``pairwise_kernel(metric)``
+returns a drop-in replacement for ``repro.core.distances.pairwise(metric)``.
 """
 from __future__ import annotations
 
@@ -17,8 +17,19 @@ import jax.numpy as jnp
 from repro.kernels import pairwise_distance as pk
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted: True on the CPU backend,
+    False on a TPU (compiled Mosaic kernels). Any other backend raises — the
+    kernels are never interpreted silently where a compiled path is expected.
+    Evaluated at trace time by the jitted wrappers below."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels need a TPU (or the CPU backend, interpreted); the "
+        f"default backend is {platform!r}. Use backend='reference' here.")
 
 
 def _pad_to(a: jnp.ndarray, m0: int, m1: int) -> jnp.ndarray:
@@ -32,7 +43,7 @@ def _pad_to(a: jnp.ndarray, m0: int, m1: int) -> jnp.ndarray:
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kernel_dot(x: jnp.ndarray, y: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarray:
     """Pairwise inner products via the MXU kernel. (C, d) x (R, d) -> (C, R)."""
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_mode() if interpret is None else interpret
     c, r = x.shape[0], y.shape[0]
     xp = _pad_to(x, pk.BC, pk.BD)
     yp = _pad_to(y, pk.BR, pk.BD)
@@ -42,7 +53,7 @@ def kernel_dot(x: jnp.ndarray, y: jnp.ndarray, interpret: bool | None = None) ->
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kernel_l1(x: jnp.ndarray, y: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarray:
     """Pairwise ℓ1 distances via the VPU kernel."""
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_mode() if interpret is None else interpret
     c, r = x.shape[0], y.shape[0]
     xp = _pad_to(x, pk.BC, pk.BD)
     yp = _pad_to(y, pk.BR, pk.BD)
@@ -68,7 +79,7 @@ def kernel_l1_centrality(x: jnp.ndarray, y: jnp.ndarray,
     the (C, R) matrix — the memory-roofline optimization for big ref sets.
     With ``ref_mask`` (shape (R,), nonzero = valid) the mean runs over the
     valid references only."""
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_mode() if interpret is None else interpret
     c, r = x.shape[0], y.shape[0]
     xp = _pad_to(x, pk.BC, pk.BD)
     yp = _pad_to(y, pk.BR, pk.BD)
@@ -129,7 +140,7 @@ def kernel_centrality_sums(x: jnp.ndarray, y: jnp.ndarray, *,
     kernel has no matmul stage; its inputs are representation-rounded
     instead (the caller's job — see ``repro.quant.backends``).
     """
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_mode() if interpret is None else interpret
     c, r = x.shape[0], y.shape[0]
     if metric == "l1":
         xp = _pad_to(x, pk.BC, pk.BD)
@@ -166,7 +177,7 @@ def kernel_topk_smallest(theta: jnp.ndarray, *, keep: int,
     smaller index — drop-in for ``jax.lax.top_k(-theta, keep)[1]`` (the round
     loop's halving step), computed by the on-chip rank/select kernel pair so
     survivor selection never leaves the chip."""
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_mode() if interpret is None else interpret
     c = theta.shape[0]
     if not 0 < keep <= c:
         raise ValueError(f"keep must be in [1, {c}], got {keep}")

@@ -10,9 +10,9 @@ metrics into two kernel families:
   128x128 systolic array at full rate.
 
 * **ℓ1 kernels** (VPU path): ``sum |x - y|`` has no matmul form. The kernel
-  tiles ``(BC, BD) x (BR, BD)`` into VMEM and accumulates f32 partial sums,
-  chunking the d-axis inside the block to bound the broadcast intermediate
-  (BC x BR x CHUNK). Two variants:
+  tiles ``(BC, BD) x (BD, BR)`` (the reference block transposed) into VMEM
+  and accumulates f32 partial sums in a lane-dense (BC, BR) tile, one
+  d-column at a time. Two variants:
     - ``l1_pairwise``  -> (C, R) distance matrix
     - ``l1_centrality``-> fused row-sum (C,): never materializes (C, R) in HBM,
       which is the memory-roofline win for large reference sets.
@@ -39,16 +39,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # Block sizes: MXU-aligned (multiples of 128 in the matmul dims). The ℓ1 VPU
-# kernel keeps the same tile footprint but chunks d to bound VMEM.
+# kernels use the same tiles: per grid step an X tile and a transposed Y tile
+# of BC*BD*4B = 128 KiB each (f32), plus one (BC, BR) f32 accumulator of
+# 64 KiB — under 1 MiB of VMEM with double-buffered inputs.
 BC = 128   # candidate rows per tile
 BR = 128   # reference rows per tile
 BD = 256   # d-axis slab per grid step
-L1_CHUNK = 16  # d-chunk inside the ℓ1 kernel: BC*BR*CHUNK*4B = 1 MiB VMEM
 
 
 # --------------------------------------------------------------------------
 # dot kernel (MXU): G[c, r] = sum_d X[c, d] * Y[r, d]
 # --------------------------------------------------------------------------
+
+def _precision(compute_dtype) -> jax.lax.Precision:
+    """f32 multiplies run at full f32 precision on the MXU (the fp32
+    backends are exact fp32, like the reference path); bf16 at its rate."""
+    return (jax.lax.Precision.HIGHEST if compute_dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
 
 def _dot_kernel(x_ref, y_ref, o_ref, *, compute_dtype):
     k = pl.program_id(2)
@@ -64,6 +72,7 @@ def _dot_kernel(x_ref, y_ref, o_ref, *, compute_dtype):
     y = y_ref[...].astype(compute_dtype)
     o_ref[...] += jax.lax.dot_general(
         x, y, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_precision(compute_dtype),
         preferred_element_type=jnp.float32,
     )
 
@@ -93,28 +102,37 @@ def dot_pairwise(x: jnp.ndarray, y: jnp.ndarray, *,
 
 
 # --------------------------------------------------------------------------
-# ℓ1 pairwise kernel (VPU): D[c, r] = sum_d |X[c, d] - Y[r, d]|
+# ℓ1 kernels (VPU): D[c, r] = sum_d |X[c, d] - Y[r, d]|
+#
+# Every intermediate is a 2-D, lane-dense (BC, BR) tile. The reference block
+# is streamed TRANSPOSED, (BD, BR), so reference r sits on lane r: each d step
+# broadcasts one X column (BC, 1) against one Y^T row (1, BR) and adds
+# |x - y| into the (BC, BR) f32 accumulator. No (BC, BR, chunk) broadcast
+# ever exists (its minor chunk axis would pad to 128 lanes in VMEM).
 # --------------------------------------------------------------------------
 
-def _l1_pairwise_kernel(x_ref, y_ref, o_ref):
-    k = pl.program_id(2)
+def _l1_slab(x_ref, yt_ref) -> jnp.ndarray:
+    """(BC, BR) f32 partial ℓ1 sums of one d-slab: sum over the BD columns
+    of X's tile (BC, BD) against the transposed reference tile (BD, BR)."""
+    x = x_ref[...].astype(jnp.float32)
+    yt = yt_ref[...].astype(jnp.float32)
+    acc = jnp.zeros((BC, BR), jnp.float32)
+    for dd in range(BD):                 # static unroll over the slab
+        acc = acc + jnp.abs(x[:, dd:dd + 1] - yt[dd:dd + 1, :])
+    return acc
 
-    @pl.when(k == 0)
+
+def _l1_pairwise_kernel(x_ref, yt_ref, o_ref):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)   # (BC, BD)
-    y = y_ref[...].astype(jnp.float32)   # (BR, BD)
-    acc = jnp.zeros_like(o_ref)
-    for c0 in range(0, BD, L1_CHUNK):    # static unroll: bound VMEM intermediate
-        xs = x[:, c0:c0 + L1_CHUNK]
-        ys = y[:, c0:c0 + L1_CHUNK]
-        acc += jnp.sum(jnp.abs(xs[:, None, :] - ys[None, :, :]), axis=-1)
-    o_ref[...] += acc
+    o_ref[...] += _l1_slab(x_ref, yt_ref)
 
 
 def l1_pairwise(x: jnp.ndarray, y: jnp.ndarray, *,
                 interpret: bool = False) -> jnp.ndarray:
+    """X: (C, d), Y: (R, d) — C, R, d already padded to block multiples."""
     c, d = x.shape
     r, _ = y.shape
     grid = (c // BC, r // BR, d // BD)
@@ -123,41 +141,29 @@ def l1_pairwise(x: jnp.ndarray, y: jnp.ndarray, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((BC, BD), lambda i, j, k: (i, k)),
-            pl.BlockSpec((BR, BD), lambda i, j, k: (j, k)),
+            pl.BlockSpec((BD, BR), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((BC, BR), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((c, r), jnp.float32),
         interpret=interpret,
-    )(x, y)
+    )(x, y.T)
 
 
 # --------------------------------------------------------------------------
 # fused ℓ1 centrality kernel: S[c] = sum_{r valid} sum_d |X[c,d] - Y[r,d]|
-# Never materializes the (C, R) matrix in HBM. Validity is a streamed (R, 1)
-# f32 mask (1.0 = count this reference), which covers both block padding and
-# the ragged engine's invalid (padded-arm) references.
+# Never materializes the (C, R) matrix in HBM. Validity is a streamed (1, R)
+# f32 mask row (1.0 = count this reference), which covers both block padding
+# and the ragged engine's invalid (padded-arm) references; it weights the
+# slab's (BC, BR) tile before the lane reduction into the (BC, 1) output.
 # --------------------------------------------------------------------------
 
-def _l1_centrality_kernel(x_ref, y_ref, m_ref, o_ref):
-    j = pl.program_id(1)
-    k = pl.program_id(2)
-
-    @pl.when((j == 0) & (k == 0))
+def _l1_centrality_kernel(x_ref, yt_ref, m_ref, o_ref):
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)   # (BC, BD)
-    y = y_ref[...].astype(jnp.float32)   # (BR, BD)
-    mask = m_ref[...]                    # (BR, 1) validity of this ref tile
-    acc = jnp.zeros_like(o_ref)          # (BC, 1)
-    for c0 in range(0, BD, L1_CHUNK):
-        xs = x[:, c0:c0 + L1_CHUNK]
-        ys = y[:, c0:c0 + L1_CHUNK]
-        a = jnp.abs(xs[:, None, :] - ys[None, :, :])   # (BC, BR, CHUNK)
-        # invalid reference rows must not count: mask the whole (r) slice
-        a = a * mask[None, :, :]
-        acc += jnp.sum(a, axis=(1, 2), keepdims=False)[:, None]
-    o_ref[...] += acc
+    a = _l1_slab(x_ref, yt_ref) * m_ref[...]            # mask invalid refs
+    o_ref[...] += jnp.sum(a, axis=1, keepdims=True)     # (BC, 1)
 
 
 def l1_centrality(x: jnp.ndarray, y: jnp.ndarray, r_true: int, *,
@@ -172,24 +178,22 @@ def l1_centrality(x: jnp.ndarray, y: jnp.ndarray, r_true: int, *,
     """
     c, d = x.shape
     r, _ = y.shape
-    if ref_mask is None:
-        mask = (jnp.arange(r) < r_true).astype(jnp.float32)
-    else:
-        mask = ref_mask.reshape(-1).astype(jnp.float32)
-        mask = mask * (jnp.arange(r) < r_true).astype(jnp.float32)
+    mask = (jnp.arange(r) < r_true).astype(jnp.float32)
+    if ref_mask is not None:
+        mask = mask * ref_mask.reshape(-1).astype(jnp.float32)
     grid = (c // BC, r // BR, d // BD)
     return pl.pallas_call(
         _l1_centrality_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((BC, BD), lambda i, j, k: (i, k)),
-            pl.BlockSpec((BR, BD), lambda i, j, k: (j, k)),
-            pl.BlockSpec((BR, 1), lambda i, j, k: (j, 0)),
+            pl.BlockSpec((BD, BR), lambda i, j, k: (k, j)),
+            pl.BlockSpec((1, BR), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((BC, 1), lambda i, j, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((c, 1), jnp.float32),
         interpret=interpret,
-    )(x, y, mask.reshape(r, 1))
+    )(x, y.T, mask.reshape(1, r))
 
 
 # --------------------------------------------------------------------------
@@ -224,6 +228,7 @@ def _dot_centrality_kernel(x_ref, y_ref, xn_ref, yn_ref, m_ref, o_ref,
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...].astype(compute_dtype), y_ref[...].astype(compute_dtype),
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_precision(compute_dtype),
         preferred_element_type=jnp.float32,
     )
 

@@ -28,6 +28,7 @@ from repro.api import KMedoidsConfig, kmedoids
 from repro.cluster import adjusted_rand_index, pam_exact, pam_pulls
 from repro.core import list_backends
 from repro.data.medoid_datasets import CLUSTER_DATASETS
+from repro.engine.programs import device_info, enable_compile_cache
 
 
 def run(n: int, d: int, k: int, dataset: str, *, metric: str = "",
@@ -82,6 +83,7 @@ def run(n: int, d: int, k: int, dataset: str, *, metric: str = "",
     out = {
         "n": n, "d": d, "k": k, "dataset": dataset, "metric": metric,
         "backend": backend, "mode": "serve" if serve else "direct",
+        **device_info(),
         "medoids": res.medoids, "cost": round(res.cost, 3),
         "ari": round(adjusted_rand_index(res.labels, labels), 4),
         "pulls": res.pulls,
@@ -138,9 +140,6 @@ def main(argv=None):
                     help="also run exact PAM (O(n^2) — keep n modest)")
     ap.add_argument("--serve", action="store_true",
                     help="route refinement through the MedoidServer")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compile cache directory (repeat "
-                         "runs skip recompiling known program signatures)")
     ap.add_argument("--trace", default=None, metavar="PATH", dest="trace_out",
                     help="stream span/round/select events to this JSONL "
                          "file (with --serve, refinement dispatches run "
@@ -150,9 +149,7 @@ def main(argv=None):
                          "server's per-bucket metrics with --serve, the "
                          "engine odometers otherwise)")
     args = ap.parse_args(argv)
-    if args.compile_cache:
-        from repro.engine.programs import enable_persistent_cache
-        enable_persistent_cache(args.compile_cache)
+    enable_compile_cache()
     session = None
     if args.trace_out:
         from repro.obs import TraceSession

@@ -40,6 +40,7 @@ from repro.core import (exact_medoid, list_backends, rand_medoid,
                         round_schedule, schedule_pulls)
 from repro.core.distributed import make_row_sharding
 from repro.data.medoid_datasets import DATASETS, planted_medoid
+from repro.engine.programs import device_info, enable_compile_cache
 from repro.runtime.fault_tolerance import elastic_remesh
 
 
@@ -64,12 +65,15 @@ def run(n: int, d: int, metric: str, budget_per_arm: int, dataset: str,
     if batch > 0 and distributed:
         raise ValueError("--batch and --distributed are mutually exclusive; "
                          "the batched engine is single-host (vmap)")
+    if distributed and len(jax.devices()) < 2:
+        raise ValueError(f"--distributed needs at least 2 devices, found "
+                         f"{len(jax.devices())}")
     data = None if batch > 0 else gen_data(key)
 
     budget = budget_per_arm * n
     sched = round_schedule(n, budget)
     out = {"n": n, "d": d, "metric": metric, "budget": budget,
-           "backend": backend, "precision": precision,
+           "backend": backend, "precision": precision, **device_info(),
            "pulls_scheduled": schedule_pulls(n, budget),
            "rounds": [(r.survivors, r.num_refs) for r in sched]}
 
@@ -80,8 +84,7 @@ def run(n: int, d: int, metric: str, budget_per_arm: int, dataset: str,
                          "run without --distributed")
     # --trace: switch the facade to the telemetry-carrying program variant
     # (answers stay bit-identical; the distributed engine isn't instrumented)
-    with_tel = trace is not None and not (distributed
-                                          and len(jax.devices()) > 1)
+    with_tel = trace is not None and not distributed
     dispatch_span = (trace.span("dispatch", mode=out.get("mode", backend))
                      if trace is not None else contextlib.nullcontext())
     t0 = time.time()
@@ -104,7 +107,7 @@ def run(n: int, d: int, metric: str, budget_per_arm: int, dataset: str,
                                 pulls=int(tel["pulls"][slot].sum()), n=n,
                                 algo="corr_sh", metric=metric,
                                 backend=backend, slot_id=slot)
-        elif distributed and len(jax.devices()) > 1:
+        elif distributed:
             mesh = elastic_remesh(preferred_tp=1)
             data_sh = jax.device_put(data, make_row_sharding(mesh))
             medoid = find_medoid(data_sh, jax.random.fold_in(key, 1),
@@ -168,9 +171,6 @@ def main(argv=None):
     ap.add_argument("--distributed", action="store_true")
     ap.add_argument("--compare", action="store_true")
     ap.add_argument("--ckpt-dir", default=None)
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compile cache directory (repeat "
-                         "runs skip recompiling known program signatures)")
     ap.add_argument("--trace", default=None, metavar="PATH", dest="trace_out",
                     help="stream span/round/select events to this JSONL "
                          "file (runs with device-resident telemetry; "
@@ -182,9 +182,7 @@ def main(argv=None):
                     help="bracket the run in jax.profiler.start_trace/"
                          "stop_trace writing here (bandit phases annotated)")
     args = ap.parse_args(argv)
-    if args.compile_cache:
-        from repro.engine.programs import enable_persistent_cache
-        enable_persistent_cache(args.compile_cache)
+    enable_compile_cache()
     session = None
     if args.trace_out or args.profile_dir:
         from repro.obs import TraceSession

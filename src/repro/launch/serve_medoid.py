@@ -14,10 +14,10 @@ distinct bucket no matter how traffic is shaped, and the compile odometer
 Per-request accounting mirrors a serving stack: queue-wait steps, batch wall
 time, and the schedule's pull count (distance evaluations) for the bucket the
 request rode in. ``warmup()`` pre-traces expected buckets — BOTH program
-variants, base and telemetry-carrying — before traffic arrives, and
-``compile_cache_dir=`` (CLI ``--compile-cache``) points jax's persistent
-compilation cache at a directory so a *restarted* server never re-compiles a
-bucket it has ever seen.
+variants, base and telemetry-carrying — before traffic arrives, and the CLI
+turns on jax's persistent compilation cache
+(:func:`repro.engine.programs.enable_compile_cache`) so a *restarted* server
+never re-compiles a bucket it has ever seen.
 
 Multi-tenant scheduling (``policy=`` / CLI ``--policy``): requests carry an
 optional priority and absolute deadline; the ``"edf"`` policy serves the
@@ -115,7 +115,6 @@ class MedoidServer:
     def __init__(self, *, metric: str = "l2", backend: str = "reference",
                  budget_per_arm: int = 24, max_batch: int = 8,
                  min_bucket: int = DEFAULT_MIN_BUCKET, seed: int = 0,
-                 compile_cache_dir: Optional[str] = None,
                  trace: Optional[TraceSession] = None,
                  policy="fifo", clock=None, collect_gaps: bool = True,
                  latency_quantile: float = 0.9, precision: str = "fp32",
@@ -127,10 +126,6 @@ class MedoidServer:
         if quant_error_model not in quant.ERROR_MODELS:
             raise ValueError(f"unknown error model {quant_error_model!r}; "
                              f"one of {quant.ERROR_MODELS}")
-        if compile_cache_dir:
-            # persistent XLA cache: a restarted server re-traces known
-            # buckets (cheap) but never re-compiles them (expensive)
-            programs.enable_persistent_cache(compile_cache_dir)
         self.metric = metric
         self.backend = backend
         # precision != "fp32" runs every dispatch on the quantized Gram
@@ -239,20 +234,17 @@ class MedoidServer:
         # certificate failed?), and each is its own cached program —
         # warming only one would leave the first metered call on another
         # variant compiling:
-        #   * base and telemetry-carrying, at the server's precision
-        #     (quantized dispatches keep the buffer for a possible
-        #     fallback, so they run donate=False — fp32 donates);
+        #   * base and telemetry-carrying, at the server's precision;
         #   * for a quantized server, additionally the exact fp32
-        #     fallback program (donate=True, no telemetry) that answers a
-        #     batch whose verification certificate failed.
-        variants = [(self.precision, with_tel, self.precision == "fp32")
-                    for with_tel in (False, True)]
+        #     fallback program (no telemetry) that answers a batch whose
+        #     verification certificate failed.
+        variants = [(self.precision, with_tel) for with_tel in (False, True)]
         if self.precision != "fp32":
-            variants.append(("fp32", False, True))
+            variants.append(("fp32", False))
         for n, d in shapes:
             n_bucket = bucket_n(max(1, int(n)), self.min_bucket)
             t0 = time.time()
-            for prec, with_tel, don in variants:
+            for prec, with_tel in variants:
                 data, lengths = pack_queries(
                     [jnp.zeros((1, int(d)), jnp.float32)],
                     min_bucket=n_bucket, pad_batch_to=self.max_batch)
@@ -260,8 +252,7 @@ class MedoidServer:
                     data, lengths, jax.random.key(0),
                     budget=self.budget_per_arm * n_bucket,
                     metric=self.metric, backend=self.backend,
-                    min_bucket=self.min_bucket, donate=don,
-                    telemetry=with_tel, precision=prec,
+                    min_bucket=self.min_bucket, telemetry=with_tel, precision=prec,
                     error_model=self.quant_error_model))
             timings["buckets"][f"{n_bucket}x{int(d)}"] = round(
                 time.time() - t0, 4)
@@ -326,14 +317,10 @@ class MedoidServer:
         t0 = time.time()
         fellback = False
         try:
-            # donate only on the fp32 path: the packed batch buffer is
-            # server-owned and dead after an fp32 dispatch, but a quantized
-            # dispatch may need it again for the exact fp32 fallback — the
-            # fallback dispatch (the buffer's last use) takes it instead
             out = ragged_medoids(
                 data, lengths, sub, budget=budget, metric=self.metric,
                 backend=self.backend, min_bucket=self.min_bucket,
-                donate=self.precision == "fp32", telemetry=with_tel,
+                telemetry=with_tel,
                 precision=self.precision,
                 error_model=self.quant_error_model)
             if self.precision == "fp32":
@@ -352,8 +339,7 @@ class MedoidServer:
                     fout = ragged_medoids(
                         data, lengths, sub, budget=budget,
                         metric=self.metric, backend=self.backend,
-                        min_bucket=self.min_bucket, donate=True,
-                        telemetry=False)
+                        min_bucket=self.min_bucket, telemetry=False)
                     medoids = jnp.where(verified, medoids, fout)
             medoids = [int(m) for m in medoids]      # block until ready
         except Exception:
@@ -515,9 +501,6 @@ def main(argv=None):
     ap.add_argument("--deadline-s", type=float, default=0.5,
                     help="relative deadline budget (seconds from admission) "
                          "for deadlined synthetic requests")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compile cache directory (restarted "
-                         "servers skip recompiling known buckets)")
     ap.add_argument("--warmup", action="store_true",
                     help="pre-trace every bucket the synthetic trace will "
                          "hit before admitting any request")
@@ -531,6 +514,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.arrivals_per_step < 1:
         ap.error("--arrivals-per-step must be >= 1")
+    programs.enable_compile_cache()
 
     session = TraceSession(args.trace_out, meta={
         "workload": "serve_medoid", "backend": args.backend,
@@ -538,7 +522,6 @@ def main(argv=None):
     srv = MedoidServer(metric=args.metric, backend=args.backend,
                        budget_per_arm=args.budget_per_arm,
                        max_batch=args.max_batch, seed=args.seed,
-                       compile_cache_dir=args.compile_cache,
                        trace=session, policy=args.policy,
                        precision=args.precision)
     trace = synthetic_trace(args.requests, args.n_min, args.n_max, args.d,
@@ -563,6 +546,7 @@ def main(argv=None):
             admitted += 1
         srv.step()
     out = srv.stats()
+    out.update(programs.device_info())
     out["wall_s"] = round(time.time() - t0, 2)
     if warmup_stats is not None:
         out["warmup"] = warmup_stats

@@ -17,8 +17,8 @@ compiled signature depends only on ``(cap, d, metric, backend)`` — an
 arbitrary insert/delete stream inside one capacity bucket reuses exactly
 one compiled program per mutation kind ("no retrace on mutate"; the
 ``"corpus"`` odometer of :mod:`repro.engine.instrument` pins it). When the
-freelist runs dry the capacity bucket **doubles** and the old buffers are
-donated to the growth program.
+freelist runs dry the capacity bucket **doubles** (one growth program
+copies the buffers into the larger bucket).
 
 Each mutation costs one n-vector of distances (O(cap) pulls, counted in
 :attr:`CorpusStore.mutation_pulls`) and updates the exact centrality of

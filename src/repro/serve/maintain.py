@@ -154,7 +154,7 @@ class MaintainedMedoid:
         meds = ragged_medoids(snap[None], jnp.asarray([n], jnp.int32), key,
                               budget=budget, metric=store.metric,
                               backend=store.backend,
-                              min_bucket=store.min_bucket, donate=True)
+                              min_bucket=store.min_bucket)
         self.medoid_slot = int(order[int(meds[0])])
         rounds = round_schedule(n_bucket, budget)
         pulls = sum(r.pulls for r in rounds[: stop_round(rounds) + 1]) \

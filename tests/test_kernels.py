@@ -79,6 +79,22 @@ def test_distance_properties(c, d):
         assert (m >= -1e-3).all()
 
 
+@pytest.mark.parametrize("platform,interpret", [("cpu", True), ("tpu", False),
+                                                ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, platform, interpret):
+    """Kernels interpret on the CPU backend, compile on a TPU, and raise on
+    any other backend instead of interpreting silently."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+    if interpret is not None:
+        assert ops.interpret_mode() is interpret
+        return
+    with pytest.raises(RuntimeError, match="TPU"):
+        ops.interpret_mode()
+    x = jnp.ones((3, 517), jnp.float32)   # a shape no other test traces
+    with pytest.raises(RuntimeError, match="TPU"):
+        ops.kernel_centrality_sums(x, x, metric="l1")
+
+
 # ------------------ fused top-k survivor-selection epilogue -----------------
 
 @pytest.mark.parametrize("c,keep", [(1, 1), (5, 2), (8, 8), (128, 64),

@@ -272,6 +272,25 @@ def test_cluster_service_routes():
 
 # --------------------------------- CLI smoke ---------------------------------
 
+def test_launch_medoid_reports_device(capsys):
+    from repro.launch import medoid as launch_medoid
+
+    launch_medoid.main(["--n", "16", "--d", "4", "--budget-per-arm", "8"])
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    dev = jax.devices()[0]
+    assert (out["platform"], out["device_kind"], out["device_count"]) == \
+        (dev.platform, dev.device_kind, len(jax.devices()))
+
+
+def test_launch_medoid_distributed_needs_two_devices(monkeypatch):
+    from repro.launch import medoid as launch_medoid
+
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+    with pytest.raises(ValueError, match="at least 2 devices"):
+        launch_medoid.run(16, 4, "", 8, "planted", distributed=True)
+
+
 def test_launch_medoid_trace_cli(tmp_path, capsys):
     from repro.launch import medoid as launch_medoid
     from repro.obs.validate import main as validate_main

@@ -6,10 +6,11 @@ The PR-6 contract under test:
   ``find_medoids_ragged`` / ``kmedoids`` calls trace one XLA program per
   distinct signature and zero afterwards (counter-based, via the monotone
   odometers of :mod:`repro.engine.instrument`);
-* **donation is safe and folded** — on CPU the donate flag folds away so
-  donating and plain callers share one compiled program; the facade's
-  self-packed (donated) ragged path answers identically to the caller-packed
-  (non-donated) path;
+* **donation only where it aliases, folded on CPU** — the corpus mutation
+  programs donate (their outputs reuse the donated buffers), and the flag
+  folds away on CPU; the facade's self-packed ragged path answers
+  identically to the caller-packed path and leaves the caller's buffer
+  alive;
 * **no host syncs in the hot path** — the engine package, the device-path
   telemetry module (``repro.obs.telemetry``) and the cluster BUILD/SWAP
   phase kernels contain no ``.item()`` / ``np.asarray`` / ``device_get``
@@ -17,8 +18,9 @@ The PR-6 contract under test:
 * **stacked schedules** — ``Schedule.stacked`` partitions exactly the
   scanned prefix ``[0, r_stop)`` into bands with the legacy entering sizes;
 * **warmup + persistent cache** — a warmed ``MedoidServer`` serves known
-  buckets with zero recompiles, and ``enable_persistent_cache`` writes XLA
-  cache entries a restarted process can reuse.
+  buckets with zero recompiles, and ``enable_compile_cache`` writes XLA
+  cache entries a restarted process can reuse, in the directory
+  ``JAX_COMPILATION_CACHE_DIR`` names.
 """
 import inspect
 import math
@@ -87,24 +89,23 @@ def test_kmedoids_identical_rerun_traces_nothing():
 # -------------------------------- donation ----------------------------------
 
 def test_donation_flag_folds_away_on_cpu():
-    kw = dict(budget=37 * 21, metric="l2", backend="reference")
-    if jax.default_backend() == "cpu":
-        assert not programs.donation_enabled()
-        # one program for both flags: no double compile, no CPU warning spam
-        assert programs.medoid_program(donate=True, **kw) \
-            is programs.medoid_program(donate=False, **kw)
-    else:
-        assert programs.donation_enabled()
-        assert programs.medoid_program(donate=True, **kw) \
-            is not programs.medoid_program(donate=False, **kw)
+    cap, d = 8, 3
+    args = (jnp.zeros((cap, d)), jnp.zeros((cap,)), jnp.zeros((cap,), bool),
+            jnp.zeros((d,)), jnp.int32(0))
+    text = programs.corpus_insert_program().lower(*args).as_text()
+    # the insert program's outputs alias its donated (buf, cent, alive)
+    # where donation is real; on CPU the flag folds away (no aliasing, no
+    # warning spam)
+    assert programs.donation_enabled() == (jax.default_backend() != "cpu")
+    assert ("tf.aliasing_output" in text) == programs.donation_enabled()
 
 
 def test_donated_facade_path_matches_nondonated():
     qs = [jax.random.normal(jax.random.fold_in(jax.random.key(6), i), (n, 4))
           for i, n in enumerate((17, 51))]
-    # list input: the facade packs (and donates) the buffer itself
+    # list input: the facade packs the buffer itself
     a = find_medoids_ragged(qs, key=jax.random.key(7), budget_per_arm=19)
-    # caller-packed input: never donated, caller's buffer must survive
+    # caller-packed input: the caller's buffer must survive
     data, lens = pack_queries(qs)
     b = find_medoids_ragged(data, lens, jax.random.key(7), budget_per_arm=19)
     assert [int(x) for x in a] == [int(x) for x in b]
@@ -204,13 +205,17 @@ import os, sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax, jax.numpy as jnp
 from repro.engine import programs
-path = programs.enable_persistent_cache(sys.argv[1])
+path = programs.enable_compile_cache()
 fn = programs.medoid_program(budget=13 * 16)
 fn(jnp.zeros((16, 3)), jax.random.key(0)).block_until_ready()
+print(path)
 print(len(os.listdir(path)))
 """
-    env = dict(os.environ, PYTHONPATH=_SRC)
-    out = subprocess.run([sys.executable, "-c", code, str(tmp_path)],
+    env = dict(os.environ, PYTHONPATH=_SRC,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, env=env, timeout=300)
     assert out.returncode == 0, out.stderr
-    assert int(out.stdout.strip().splitlines()[-1]) >= 1
+    path, count = out.stdout.strip().splitlines()[-2:]
+    assert path == str(tmp_path)          # the variable wins, no other dir
+    assert int(count) >= 1
