@@ -94,6 +94,10 @@ class DistanceBackend:
     # ships, say, an in-VMEM BUILD-delta kernel without any engine changes.
     fused_estimators: Mapping[str, Callable[[str], Callable]] = \
         field(default_factory=dict)
+    # (candidate, reference, width) block the backend's kernels pad every
+    # call to (``kops.TILE`` for the Pallas backends); ``None`` = no
+    # padding. Feeds the engine's ``computed`` work tally.
+    tile: Optional[tuple[int, int, int]] = None
 
 
 _REGISTRY: dict[str, DistanceBackend] = {}
@@ -168,6 +172,7 @@ register_backend(DistanceBackend(
     centrality_sums=_pairwise_rowsum_centrality,
     materializes_block=True,
     description="Pallas (C, R) block kernels + out-of-kernel row sum",
+    tile=kops.TILE,
 ))
 
 # The fused centrality kernels double as the fused ``medoid_centrality``
@@ -181,6 +186,7 @@ register_backend(DistanceBackend(
     materializes_block=False,
     description="fused in-kernel reference reduction (no (C, R) in HBM)",
     fused_estimators=_FUSED_ESTIMATORS,
+    tile=kops.TILE,
 ))
 
 
@@ -204,4 +210,5 @@ register_backend(DistanceBackend(
     survivor_topk=_topk_epilogue,
     survivor_order=_order_epilogue,
     fused_estimators=_FUSED_ESTIMATORS,
+    tile=kops.TILE,
 ))

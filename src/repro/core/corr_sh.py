@@ -105,7 +105,9 @@ def _medoid_impl(data: jnp.ndarray, key: jax.Array, *, budget: int,
     fn = programs.medoid_program(budget=budget, metric=metric,
                                  backend=backend, telemetry=telemetry, precision=precision,
                                  error_model=error_model)
-    return fn(data, key)
+    out = fn(data, key)
+    programs.charge_work("medoid", fn, data)
+    return out
 
 
 def _batch_impl(data: jnp.ndarray, key: jax.Array, *, budget: int,
@@ -128,7 +130,9 @@ def _batch_impl(data: jnp.ndarray, key: jax.Array, *, budget: int,
     fn = programs.batch_program(budget=budget, metric=metric,
                                 backend=backend, telemetry=telemetry, precision=precision,
                                 error_model=error_model)
-    return fn(data, key)
+    out = fn(data, key)
+    programs.charge_work("batch", fn, data)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -149,11 +153,13 @@ def ragged_medoids(data: jnp.ndarray, lengths, key: jax.Array, *,
                    budget: int, metric: str = "l2",
                    backend: str = "reference",
                    min_bucket: int = DEFAULT_MIN_BUCKET,
-                   telemetry: bool = False,
+                   telemetry=False,
                    precision: str = "fp32", error_model: str = "probe"):
     """Ragged multi-query medoid: ``data (B, n_max, d)`` + per-query
     ``lengths (B,)`` -> ``(B,)`` medoid indices (each < its query's length);
-    ``((B,) indices, telemetry)`` with ``telemetry``.
+    ``((B,) indices, telemetry)`` with ``telemetry``, where
+    ``telemetry="gap"`` carries only the ``(B,)`` output-round winner gaps
+    (see :func:`repro.engine.programs.ragged_program`).
 
     Queries of heterogeneous sizes ride one XLA program: ``n_max`` is rounded
     up to a power-of-two bucket (see :mod:`repro.core.bucketing` — this caps
@@ -195,7 +201,9 @@ def ragged_medoids(data: jnp.ndarray, lengths, key: jax.Array, *,
                                  metric=metric, backend=backend,
                                  telemetry=telemetry, precision=precision,
                                  error_model=error_model)
-    return fn(data, lengths, key)
+    out = fn(data, lengths, key)
+    programs.charge_work("ragged", fn, data)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -69,9 +69,12 @@ ScoreFn = Callable[..., Tuple[jnp.ndarray, Any]]
 
 @dataclass(frozen=True)
 class ArmEstimator:
-    """One arm-loss estimator: a name (for registries/telemetry) + score fn."""
+    """One arm-loss estimator: a name (for registries/telemetry) + score fn.
+    ``tile`` is the (candidate, reference, width) block its kernel pads a
+    call to (``None``: no padding), for the engine's work tally."""
     name: str
     score: ScoreFn
+    tile: Optional[Tuple[int, int, int]] = None
 
 
 # ------------------------- estimator factory registry -----------------------
@@ -134,6 +137,7 @@ def medoid_centrality(backend=None, metric: str = "l2", *,
     from repro.core import distances
     from repro.core.backend import get_backend
 
+    tile = None
     if pairwise_fn is not None:
         def plain(x, y):
             return jnp.sum(pairwise_fn(x, y), axis=1)
@@ -146,13 +150,14 @@ def medoid_centrality(backend=None, metric: str = "l2", *,
         fn = fused(metric) if fused is not None else be.centrality_sums(metric)
         plain = fn
         masked = _masked_centrality_fn(be, fn, metric)
+        tile = be.tile
 
     def score(cand, ref_rows, *, refs, ref_mask=None):
         if ref_mask is None:
             return plain(cand, ref_rows), None
         return masked(cand, ref_rows, ref_mask), None
 
-    return ArmEstimator("medoid_centrality", score)
+    return ArmEstimator("medoid_centrality", score, tile)
 
 
 def build_delta(backend=None, metric: str = "l2", *,
@@ -178,7 +183,7 @@ def build_delta(backend=None, metric: str = "l2", *,
             blk = jnp.minimum(pw(cand, ref_rows), d1[refs][None, :])
             return distances.masked_rowsum(blk, ref_mask), None
 
-    return ArmEstimator("build_delta", score)
+    return ArmEstimator("build_delta", score, be.tile)
 
 
 def swap_delta(backend=None, metric: str = "l2", *, d1: jnp.ndarray,
@@ -221,7 +226,7 @@ def swap_delta(backend=None, metric: str = "l2", *, d1: jnp.ndarray,
                      + term @ onehot)                         # (C, k)
             return jnp.min(delta, axis=1), delta
 
-    return ArmEstimator("swap_delta", score)
+    return ArmEstimator("swap_delta", score, be.tile)
 
 
 register_estimator("medoid_centrality", medoid_centrality)

@@ -57,6 +57,7 @@ from typing import TYPE_CHECKING, Any, Callable, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
+from repro.engine import instrument
 from repro.engine.schedule import Round, StackedBand, as_schedule
 from repro.obs import telemetry as obs_telemetry
 
@@ -253,6 +254,8 @@ def _scan_band(problem: HalvingProblem, band: StackedBand, order_fn: OrderFn,
         buf = buf[order_fn(theta)]        # stable: live ascending, dead last
         return (key, buf), ys
 
+    instrument.note_score(width, cap, data.shape[1], runs=len(band),
+                          tile=est.tile)
     (key, buf), rows = jax.lax.scan(body, (key, buf), xs)
     return key, buf, rows
 
@@ -310,6 +313,8 @@ def _scan_band_widened(problem: HalvingProblem, band: StackedBand,
         buf = buf[order]                  # stable: live ascending, dead last
         return (key, buf, live), ys
 
+    instrument.note_score(width, cap, data.shape[1], runs=len(band),
+                          tile=est.tile)
     (key, buf, live), rows = jax.lax.scan(body, (key, buf, live), xs)
     return key, buf, live, rows
 
@@ -355,6 +360,8 @@ def _run_halving_widened(problem: HalvingProblem, sched, order_fn: OrderFn,
         refs = sample_refs(sub, n, rd.num_refs)
         ref_mask = None
         denom = refs.shape[0]              # static Python int
+    instrument.note_score(survivors.shape[0], refs.shape[0], data.shape[1],
+                          tile=est.tile)
     sums, aux = est.score(data[survivors], data[refs], refs=refs,
                           ref_mask=ref_mask)
     theta = sums / denom
@@ -452,6 +459,8 @@ def run_halving(problem: HalvingProblem, schedule: Sequence[Round],
         refs = sample_refs(sub, n, rd.num_refs)
         ref_mask = None
         denom = refs.shape[0]              # static Python int
+    instrument.note_score(survivors.shape[0], refs.shape[0], data.shape[1],
+                          tile=est.tile)
     sums, aux = est.score(data[survivors], data[refs], refs=refs,
                           ref_mask=ref_mask)
     theta = sums / denom

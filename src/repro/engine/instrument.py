@@ -17,13 +17,86 @@ steady-state claim of the one-program refactor — "repeated same-shape calls
 never retrace" — is exactly ``trace delta == 0`` while ``dispatch delta``
 grows, and ``counters()`` emits the full snapshot into ``BENCH_engine.json``
 so the dispatch-bound -> compute-bound shift is visible per PR.
+
+A third odometer tallies distance work, in ``|x - y|`` terms (candidate
+rows x reference rows x width). At trace time the round loop reports the
+static block shape of every estimator call (:func:`note_score`) into the
+open :func:`tally`; the program keeps that per-dispatch :class:`Work` by
+signature, and the host wrapper adds it on each dispatch
+(:func:`note_work`). ``called`` is the block the engine asks the estimator
+for (band width and reference buffer included); ``computed`` is the same
+block padded to the kernel's tile — what the kernel actually evaluates.
+No device work, no host sync.
 """
 from __future__ import annotations
 
+import contextlib
 from collections import Counter
+from dataclasses import dataclass
+from typing import Optional
 
 _TRACES: Counter = Counter()
 _DISPATCHES: Counter = Counter()
+_CALLED: Counter = Counter()
+_COMPUTED: Counter = Counter()
+_TALLIES: list = []          # open trace-time (Work, copies) collectors
+
+
+@dataclass
+class Work:
+    """Distance terms of one dispatch: asked for (``called``) and evaluated
+    after tile padding (``computed``)."""
+    called: int = 0
+    computed: int = 0
+
+
+def _padded(size: int, block: int) -> int:
+    return -(-size // block) * block
+
+
+def note_score(rows: int, refs: int, width: int, *, runs: int = 1,
+               tile: Optional[tuple[int, int, int]] = None) -> None:
+    """Record one estimator call over a ``(rows, width) x (refs, width)``
+    block that runs ``runs`` times per program run (call at trace time,
+    outside any scan body: a scan body is traced once but runs once per
+    scanned round). ``tile`` = (row, reference, width) block of the kernel
+    that evaluates it; ``None`` = no padding. Does nothing outside a
+    :func:`tally`."""
+    if not _TALLIES:
+        return
+    work, copies = _TALLIES[-1]
+    called = rows * refs * width
+    computed = called if tile is None else (
+        _padded(rows, tile[0]) * _padded(refs, tile[1])
+        * _padded(width, tile[2]))
+    work.called += copies * runs * called
+    work.computed += copies * runs * computed
+
+
+@contextlib.contextmanager
+def tally(copies: int = 1):
+    """Collect the :func:`note_score` calls traced inside the block into a
+    fresh :class:`Work`, each counted ``copies`` times (the batch size of a
+    vmapped body, whose estimator calls are traced at per-query shapes)."""
+    work = Work()
+    _TALLIES.append((work, copies))
+    try:
+        yield work
+    finally:
+        _TALLIES.pop()
+
+
+def note_work(kind: str, work: Work) -> None:
+    """Add one dispatch's distance work to the ``kind`` odometer (host
+    side, next to :func:`note_dispatch`)."""
+    _CALLED[kind] += work.called
+    _COMPUTED[kind] += work.computed
+
+
+def work_counters() -> dict:
+    """Snapshot of the work odometer (per kind, in distance terms)."""
+    return {"called": dict(sorted(_CALLED.items())),
+            "computed": dict(sorted(_COMPUTED.items()))}
 
 
 def note_trace(kind: str) -> None:
@@ -73,12 +146,14 @@ class deltas:
     def __enter__(self) -> "deltas":
         self._t0 = Counter(_TRACES)
         self._d0 = Counter(_DISPATCHES)
-        self._t1 = self._d1 = None
+        self._w0 = (Counter(_CALLED), Counter(_COMPUTED))
+        self._t1 = self._d1 = self._w1 = None
         return self
 
     def __exit__(self, *exc) -> None:
         self._t1 = Counter(_TRACES)
         self._d1 = Counter(_DISPATCHES)
+        self._w1 = (Counter(_CALLED), Counter(_COMPUTED))
 
     def _now(self) -> tuple[Counter, Counter]:
         if self._t1 is not None:
@@ -98,6 +173,12 @@ class deltas:
         if kind is not None:
             return cur[kind] - self._d0[kind]
         return sum(cur.values()) - sum(self._d0.values())
+
+    def work(self, kind: str) -> Work:
+        """Distance work dispatched since enter by the ``kind`` programs."""
+        called, computed = self._w1 or (_CALLED, _COMPUTED)
+        return Work(called[kind] - self._w0[0][kind],
+                    computed[kind] - self._w0[1][kind])
 
     def counters(self) -> dict:
         """Per-kind nonzero deltas, same shape as the module snapshot."""

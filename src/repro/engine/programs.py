@@ -8,9 +8,14 @@ clustering pipeline all share literally the same compiled programs, keyed by
 ``(kind, schedule config, backend, telemetry)`` plus jax's own
 shape key. Repeated same-shape calls never retrace (asserted counter-based
 in ``tests/test_oneprogram.py`` via :mod:`repro.engine.instrument`); a
-telemetry-carrying variant is its own cached program (more outputs), so
-turning telemetry on costs one extra trace per signature — once — and
-nothing per call thereafter.
+telemetry-carrying variant is its own cached program (more outputs): one
+extra trace per signature, and on every call the per-round sorts of its
+statistics (``"telemetry"`` on the trace odometer counts its traces).
+
+**Distance work**: each medoid program tallies, at trace time, the
+distance work one dispatch asks its estimator for and what the kernel's
+tiles evaluate (:func:`repro.engine.instrument.tally`), per data shape;
+:func:`charge_work` adds it to the work odometer on every dispatch.
 
 **Buffer donation**: only the corpus insert/delete programs donate, because
 only their outputs have the donated buffers' shapes and can reuse them in
@@ -26,6 +31,7 @@ but never re-*compiles* them.
 """
 from __future__ import annotations
 
+import functools
 import os
 from pathlib import Path
 from typing import Callable
@@ -40,6 +46,8 @@ from repro.engine.schedule import round_schedule
 from repro.obs import telemetry as obs_telemetry
 
 _PROGRAMS: dict[tuple, Callable] = {}
+# program -> {data shape: per-dispatch distance work}, filled at trace time
+_WORK: dict[Callable, dict[tuple, instrument.Work]] = {}
 
 
 def donation_enabled() -> bool:
@@ -63,6 +71,34 @@ def program_cache_info() -> dict:
     for key in _PROGRAMS:
         info[key[0]] = info.get(key[0], 0) + 1
     return dict(sorted(info.items()))
+
+
+def _jit_tallied(impl: Callable, batched: bool) -> Callable:
+    """``jax.jit(impl)`` that keeps, per traced data shape, the distance
+    work one dispatch asks for (``batched``: the leading axis of the data
+    is a vmapped batch, whose estimator calls trace at per-query shapes)."""
+    table: dict[tuple, instrument.Work] = {}
+
+    @functools.wraps(impl)
+    def tallied(data, *args):
+        with instrument.tally(data.shape[0] if batched else 1) as work:
+            out = impl(data, *args)
+        table[tuple(data.shape)] = work
+        return out
+
+    fn = jax.jit(tallied)
+    _WORK[fn] = table
+    return fn
+
+
+def charge_work(kind: str, fn: Callable, data) -> None:
+    """Add one dispatch of the medoid program ``fn`` on ``data`` to the
+    ``kind`` work odometer (host side; the counts are static). A call
+    under an outer transformation traced other shapes and is not a
+    dispatch: nothing is charged."""
+    work = _WORK[fn].get(tuple(data.shape))
+    if work is not None:
+        instrument.note_work(kind, work)
 
 
 def _memo(key: tuple, build: Callable[[], Callable]) -> Callable:
@@ -110,6 +146,8 @@ def medoid_program(*, budget: int, metric: str = "l2",
     def build():
         def impl(data: jnp.ndarray, key: jax.Array):
             instrument.note_trace("medoid")
+            if telemetry:
+                instrument.note_trace("telemetry")
             rounds = round_schedule(data.shape[0], budget)
             if precision == "fp32":
                 if not rounds:                    # n == 1
@@ -137,7 +175,7 @@ def medoid_program(*, budget: int, metric: str = "l2",
             winner, verified = quant.exact_winner(problem, out, metric)
             return (winner, verified, out.telemetry) if telemetry \
                 else (winner, verified)
-        return jax.jit(impl)
+        return _jit_tallied(impl, batched=False)
 
     return _memo(("medoid", budget, metric, eff_backend, telemetry,
                   precision, eff_err), build)
@@ -163,6 +201,8 @@ def batch_program(*, budget: int, metric: str = "l2",
     def build():
         def impl(data: jnp.ndarray, key: jax.Array):
             instrument.note_trace("batch")
+            if telemetry:
+                instrument.note_trace("telemetry")
             if data.ndim != 3:
                 raise ValueError(f"expected (B, n, d) batch, "
                                  f"got shape {data.shape}")
@@ -200,7 +240,7 @@ def batch_program(*, budget: int, metric: str = "l2",
                     else (winner, verified)
 
             return jax.vmap(one)(data, keys)
-        return jax.jit(impl)
+        return _jit_tallied(impl, batched=True)
 
     return _memo(("batch", budget, metric, eff_backend, telemetry,
                   precision, eff_err), build)
@@ -208,7 +248,7 @@ def batch_program(*, budget: int, metric: str = "l2",
 
 def ragged_program(*, n_bucket: int, budget: int, metric: str = "l2",
                    backend: str = "reference",
-                   telemetry: bool = False, precision: str = "fp32",
+                   telemetry=False, precision: str = "fp32",
                    error_model: str = "probe") -> Callable:
     """Jitted ragged medoid: ``(data (B, n_bucket, d), lengths (B,), key) ->
     (B,) indices`` — or ``((B,) indices, telemetry)`` with ``telemetry``
@@ -217,23 +257,34 @@ def ragged_program(*, n_bucket: int, budget: int, metric: str = "l2",
     bucket's and broadcast). Padded arms are masked out of every round (arm
     and reference roles both); a query filling its bucket is bit-identical
     to the single-query program. Quantized programs additionally return the
-    per-query ``(B,) verified`` certificate — see :func:`medoid_program`."""
+    per-query ``(B,) verified`` certificate — see :func:`medoid_program`.
+
+    ``telemetry="gap"`` returns, in the telemetry's place, only each
+    query's ``(B,)`` output-round winner gap
+    (:func:`repro.obs.telemetry.winner_gap`, bit-identical to the full
+    telemetry's ``gap[:, r_stop]``): the one number of the per-round
+    telemetry a server keeps, without its per-round sorts."""
     eff_backend, eff_err = _quant_config(precision, error_model, backend)
+    rows = telemetry is True              # the per-round variant
 
     def build():
         def impl(data: jnp.ndarray, lengths: jnp.ndarray,
                  key: jax.Array):
             instrument.note_trace("ragged")
+            if rows:
+                instrument.note_trace("telemetry")
             b = data.shape[0]
             rounds = round_schedule(n_bucket, budget)
             if not rounds:                        # n_bucket == 1
                 winners = jnp.zeros((b,), jnp.int32)
                 outs = (winners,) if precision == "fp32" \
                     else (winners, jnp.ones((b,), bool))
-                if telemetry:
+                if rows:
                     outs = outs + (jax.tree_util.tree_map(
                         lambda x: jnp.broadcast_to(x, (b,) + x.shape),
                         obs_telemetry.empty()),)
+                elif telemetry:                   # fewer than two arms
+                    outs = outs + (jnp.full((b,), jnp.nan, jnp.float32),)
                 return outs[0] if len(outs) == 1 else outs
             valid = (jnp.arange(n_bucket, dtype=jnp.int32)[None, :]
                      < lengths[:, None])
@@ -249,21 +300,24 @@ def ragged_program(*, n_bucket: int, budget: int, metric: str = "l2",
                 if precision == "fp32":
                     out = run_halving(problem, rounds, key=k,
                                       survivor_order=order_fn,
-                                      telemetry=telemetry)
-                    return (out.winner, out.telemetry) if telemetry \
-                        else out.winner
-                from repro import quant
+                                      telemetry=rows)
+                    outs = (out.winner,)
+                else:
+                    from repro import quant
 
-                widen = quant.margin(x, metric, precision, model=eff_err)
-                out = run_halving(problem, rounds, key=k,
-                                  survivor_order=order_fn,
-                                  telemetry=telemetry, widen=widen)
-                winner, verified = quant.exact_winner(problem, out, metric)
-                return (winner, verified, out.telemetry) if telemetry \
-                    else (winner, verified)
+                    widen = quant.margin(x, metric, precision, model=eff_err)
+                    out = run_halving(problem, rounds, key=k,
+                                      survivor_order=order_fn,
+                                      telemetry=rows, widen=widen)
+                    outs = quant.exact_winner(problem, out, metric)
+                if rows:
+                    outs = outs + (out.telemetry,)
+                elif telemetry:
+                    outs = outs + (obs_telemetry.winner_gap(out.theta),)
+                return outs[0] if len(outs) == 1 else outs
 
             return jax.vmap(one)(data, valid, keys)
-        return jax.jit(impl)
+        return _jit_tallied(impl, batched=True)
 
     return _memo(("ragged", n_bucket, budget, metric, eff_backend,
                   telemetry, precision, eff_err), build)

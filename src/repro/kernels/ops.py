@@ -16,6 +16,10 @@ import jax.numpy as jnp
 
 from repro.kernels import pairwise_distance as pk
 
+# The (candidate, reference, width) block every wrapper below pads to: the
+# shape of the work a kernel call actually evaluates.
+TILE = (pk.BC, pk.BR, pk.BD)
+
 
 def interpret_mode() -> bool:
     """Whether the Pallas kernels run interpreted: True on the CPU backend,

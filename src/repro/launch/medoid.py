@@ -15,8 +15,8 @@ device-resident round telemetry (bit-identical answers, same single
 dispatch) and streams span / round / select events to JSONL;
 ``--metrics-out PATH`` writes the engine odometers as a Prometheus text
 exposition; ``--profile-dir DIR`` brackets the run in
-``jax.profiler.start_trace``/``stop_trace`` with the bandit phases
-annotated onto the profiler timeline.
+``jax.profiler.start_trace``/``stop_trace`` (the session's spans are
+``medoid.*`` annotations on its timeline).
 
 Example:
   PYTHONPATH=src python -m repro.launch.medoid --n 4096 --d 512 \
@@ -180,14 +180,13 @@ def main(argv=None):
                          "Prometheus text exposition on exit")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="bracket the run in jax.profiler.start_trace/"
-                         "stop_trace writing here (bandit phases annotated)")
+                         "stop_trace writing here (medoid.* spans annotated)")
     args = ap.parse_args(argv)
     enable_compile_cache()
     session = None
     if args.trace_out or args.profile_dir:
         from repro.obs import TraceSession
         session = TraceSession(args.trace_out,
-                               annotate=args.profile_dir is not None,
                                profiler_dir=args.profile_dir,
                                meta={"workload": "medoid",
                                      "backend": args.backend, "n": args.n,

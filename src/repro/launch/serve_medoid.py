@@ -11,11 +11,12 @@ bucket-derived budget — the engine compiles at most one XLA program per
 distinct bucket no matter how traffic is shaped, and the compile odometer
 (``ragged_compile_count``) lets tests and benchmarks assert exactly that.
 
-Per-request accounting mirrors a serving stack: queue-wait steps, batch wall
-time, and the schedule's pull count (distance evaluations) for the bucket the
-request rode in. ``warmup()`` pre-traces expected buckets — BOTH program
-variants, base and telemetry-carrying — before traffic arrives, and the CLI
-turns on jax's persistent compilation cache
+Per-request accounting mirrors a serving stack: queue wait (in scheduler
+steps on the request, in seconds in the metrics), batch wall time, and the
+schedule's pull count (distance evaluations) for the bucket the request rode
+in. ``warmup()`` pre-traces expected buckets — exactly the program variants
+a live dispatch can select — before traffic arrives, and the CLI turns on
+jax's persistent compilation cache
 (:func:`repro.engine.programs.enable_compile_cache`) so a *restarted* server
 never re-compiles a bucket it has ever seen.
 
@@ -28,14 +29,22 @@ reproduces the original arrival-order behavior exactly.
 
 Observability (see :mod:`repro.obs`): every server carries a
 :class:`~repro.obs.metrics.ServerMetrics` bundle — per-bucket
-request/answer/pull counters plus queue-wait, batch-occupancy and
-compile-vs-steady dispatch-latency histograms — exposed as a JSON
-:meth:`MedoidServer.metrics` snapshot and a Prometheus text
-:meth:`MedoidServer.exposition` (CLI ``--metrics-out``). Passing a
-:class:`~repro.obs.trace.TraceSession` (CLI ``--trace``) additionally runs
-every dispatch with device-resident round telemetry and streams span /
+request/answer/pull counters plus queue-wait (seconds), batch-occupancy,
+compile-vs-steady dispatch-latency and winner-gap histograms — exposed as a
+JSON :meth:`MedoidServer.metrics` snapshot and a Prometheus text
+:meth:`MedoidServer.exposition` (CLI ``--metrics-out``). ``submit`` and
+``step`` always run inside ``medoid.*`` profiler spans
+(:func:`repro.obs.span`): ``medoid.submit`` (``rid``), ``medoid.step``
+(``step``) and, inside a step, ``medoid.schedule``, ``medoid.pack`` (the
+padded batch and the dispatch key), ``medoid.dispatch`` (``dispatch``
+number and the space-separated ``rids`` it answers), ``medoid.wait``,
+``medoid.gaps`` and ``medoid.account``. The winner gap comes from the
+dispatch program itself (one ``(B,)`` output, fetched with the answers).
+Passing a :class:`~repro.obs.trace.TraceSession` (CLI ``--trace``) instead
+runs every dispatch with device-resident round telemetry and streams span /
 round / select events to JSONL — with per-round pull sums that reconcile
-exactly with the reported totals (``python -m repro.obs.validate`` checks).
+exactly with the reported totals (``python -m repro.obs.validate``
+checks).
 
 Example:
   PYTHONPATH=src python -m repro.launch.serve_medoid --requests 24 \
@@ -53,6 +62,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import get_backend, list_backends, round_schedule
 from repro.core.bucketing import DEFAULT_MIN_BUCKET, bucket_n, pack_queries
@@ -60,7 +70,7 @@ from repro.core.corr_sh import ragged_compile_count, ragged_medoids
 from repro.core.distances import METRICS
 from repro.engine import programs, stop_round
 from repro.obs import ServerMetrics, TraceSession, instrument_exposition, \
-    telemetry_to_host
+    span, telemetry_to_host
 from repro.serve.scheduler import LatencyModel, resolve_policy
 from repro import quant
 
@@ -152,9 +162,10 @@ class MedoidServer:
         # nothing on the device path); a TraceSession additionally switches
         # every dispatch to the telemetry-carrying program variant (same
         # single dispatch, bit-identical answers) and streams span / round /
-        # select events to JSONL. ``collect_gaps`` rides the same telemetry
-        # variant WITHOUT a trace session to feed the winner-gap hardness
-        # histogram (answers stay bit-identical either way).
+        # select events to JSONL. ``collect_gaps`` without a trace session
+        # adds only the output round's winner gap to the plain program's
+        # outputs, for the winner-gap hardness histogram (answers stay
+        # bit-identical either way).
         self.trace = trace
         self.collect_gaps = collect_gaps
         self._metrics = ServerMetrics()
@@ -172,9 +183,13 @@ class MedoidServer:
     def policy(self) -> str:
         return getattr(self._policy, "name", type(self._policy).__name__)
 
-    @property
-    def _telemetry_on(self) -> bool:
-        return self.trace is not None or self.collect_gaps
+    def _telemetry(self):
+        """The ``telemetry`` argument of a live dispatch: the per-round
+        variant (``True``) only with a TraceSession attached; the winner
+        gaps alone (``"gap"``) when gaps are collected without one."""
+        if self.trace is not None:
+            return True
+        return "gap" if self.collect_gaps else False
 
     # ------------------------------- admission ----------------------------
     def submit(self, data: jnp.ndarray, rid: Optional[int] = None, *,
@@ -187,24 +202,26 @@ class MedoidServer:
         ``now() + budget`` for a relative budget) feed the scheduling
         policy; under the default FIFO policy they are recorded but do not
         reorder anything."""
-        data = jnp.asarray(data)
-        if data.ndim != 2:
-            raise ValueError(f"query must be (n, d), got shape {data.shape}")
-        if data.shape[0] < 1:
-            raise ValueError("all-padding query rejected: n must be >= 1")
         if rid is None:
             rid = self._next_rid
-        if rid in self.done or rid in self.shed \
-                or any(q.rid == rid for q in self.queue):
-            raise ValueError(f"duplicate request id {rid}")
-        self._next_rid = max(self._next_rid, rid) + 1
-        self.queue.append(MedoidRequest(rid=rid, data=data,
-                                        submit_step=self._step,
-                                        priority=priority,
-                                        deadline_s=deadline_s,
-                                        submit_s=self._clock()))
-        self._metrics.record_submit(
-            self._bucket_label(*self._bucket_key(self.queue[-1])))
+        with span("submit", rid=rid):
+            data = jnp.asarray(data)
+            if data.ndim != 2:
+                raise ValueError(
+                    f"query must be (n, d), got shape {data.shape}")
+            if data.shape[0] < 1:
+                raise ValueError("all-padding query rejected: n must be >= 1")
+            if rid in self.done or rid in self.shed \
+                    or any(q.rid == rid for q in self.queue):
+                raise ValueError(f"duplicate request id {rid}")
+            self._next_rid = max(self._next_rid, rid) + 1
+            self.queue.append(MedoidRequest(rid=rid, data=data,
+                                            submit_step=self._step,
+                                            priority=priority,
+                                            deadline_s=deadline_s,
+                                            submit_s=self._clock()))
+            self._metrics.record_submit(
+                self._bucket_label(*self._bucket_key(self.queue[-1])))
         return rid
 
     def now(self) -> float:
@@ -228,23 +245,21 @@ class MedoidServer:
         timings: dict = {"buckets": {}, "traces": 0, "wall_s": 0.0}
         compiles0 = ragged_compile_count()
         t_all = time.time()
-        # warm EVERY program variant a live dispatch can select, at its
-        # exact dispatch-time cache key. The variant depends on runtime
-        # state (trace attached? gap collection toggled? quantized
-        # certificate failed?), and each is its own cached program —
-        # warming only one would leave the first metered call on another
-        # variant compiling:
-        #   * base and telemetry-carrying, at the server's precision;
+        # warm exactly the program variants a live dispatch can select, at
+        # their dispatch-time cache keys (each is its own cached program):
+        #   * the server's variant (:meth:`_telemetry`: per-round
+        #     telemetry with a TraceSession, else plain with or without the
+        #     winner gaps), at the server's precision;
         #   * for a quantized server, additionally the exact fp32
-        #     fallback program (no telemetry) that answers a batch whose
-        #     verification certificate failed.
-        variants = [(self.precision, with_tel) for with_tel in (False, True)]
+        #     fallback program (no telemetry, no gaps) that answers a batch
+        #     whose verification certificate failed.
+        variants = [(self.precision, self._telemetry())]
         if self.precision != "fp32":
             variants.append(("fp32", False))
         for n, d in shapes:
             n_bucket = bucket_n(max(1, int(n)), self.min_bucket)
             t0 = time.time()
-            for prec, with_tel in variants:
+            for prec, telemetry in variants:
                 data, lengths = pack_queries(
                     [jnp.zeros((1, int(d)), jnp.float32)],
                     min_bucket=n_bucket, pad_batch_to=self.max_batch)
@@ -252,8 +267,8 @@ class MedoidServer:
                     data, lengths, jax.random.key(0),
                     budget=self.budget_per_arm * n_bucket,
                     metric=self.metric, backend=self.backend,
-                    min_bucket=self.min_bucket, telemetry=with_tel, precision=prec,
-                    error_model=self.quant_error_model))
+                    min_bucket=self.min_bucket, telemetry=telemetry,
+                    precision=prec, error_model=self.quant_error_model))
             timings["buckets"][f"{n_bucket}x{int(d)}"] = round(
                 time.time() - t0, 4)
         timings["traces"] = ragged_compile_count() - compiles0
@@ -282,6 +297,13 @@ class MedoidServer:
         self._step += 1
         if not self.queue:
             return []
+        with span("step", step=self._step):
+            with span("schedule"):
+                batch = self._schedule()
+            return self._dispatch(batch) if batch else []
+
+    def _schedule(self) -> list[MedoidRequest]:
+        """Ask the policy for the next batch; shed what it gives up on."""
         now = self._clock()
         batch, rest, shed = self._policy.select(
             self.queue, now=now, max_batch=self.max_batch,
@@ -298,50 +320,63 @@ class MedoidServer:
                 self.trace.event("shed", rid=q.rid, bucket=label, n=q.n,
                                  deadline_s=q.deadline_s, step=self._step)
         self.queue = rest
-        if not batch:
-            return []
+        return batch
+
+    def _dispatch(self, batch: list[MedoidRequest]) -> list[MedoidRequest]:
+        """Answer one bucket group in one ragged dispatch and account it."""
         bkey = self._bucket_key(batch[0])
         n_bucket, _ = bkey
 
         # (max_batch, n_bucket, d) with dummy length-1 tail slots: group size
-        # never changes the compiled signature
-        data, lengths = pack_queries([q.data for q in batch],
-                                     min_bucket=self.min_bucket,
-                                     pad_batch_to=self.max_batch)
+        # never changes the compiled signature; the dispatch's key with them
+        with span("pack"):
+            data, lengths = pack_queries([q.data for q in batch],
+                                         min_bucket=self.min_bucket,
+                                         pad_batch_to=self.max_batch)
+            self._key, sub = jax.random.split(self._key)
         budget = self.budget_per_arm * n_bucket
-        self._key, sub = jax.random.split(self._key)
-
         label = self._bucket_label(*bkey)
-        with_tel = self._telemetry_on
+        telemetry = self._telemetry()
+        with_tel = telemetry is True
+        quantized = self.precision != "fp32"
+        number = self.dispatches + 1
+        ids = {"dispatch": number,
+               "rids": " ".join(str(q.rid) for q in batch)}
         compiles0 = ragged_compile_count()
+        start_s = self._clock()
         t0 = time.time()
         fellback = False
         try:
-            out = ragged_medoids(
-                data, lengths, sub, budget=budget, metric=self.metric,
-                backend=self.backend, min_bucket=self.min_bucket,
-                telemetry=with_tel,
-                precision=self.precision,
-                error_model=self.quant_error_model)
-            if self.precision == "fp32":
-                medoids, tel = out if with_tel else (out, None)
-            else:
-                if with_tel:
-                    medoids, verified, tel = out
-                else:
-                    (medoids, verified), tel = out, None
-                if not bool(jnp.all(verified)):
+            with span("dispatch", **ids):
+                out = ragged_medoids(
+                    data, lengths, sub, budget=budget, metric=self.metric,
+                    backend=self.backend, min_bucket=self.min_bucket,
+                    telemetry=telemetry, precision=self.precision,
+                    error_model=self.quant_error_model)
+            # outputs: indices[, verified][, telemetry or gaps]
+            out = out if isinstance(out, tuple) else (out,)
+            medoids = out[0]
+            verified = out[1] if quantized else None
+            tel = out[-1] if with_tel else None
+            gap = out[-1] if telemetry == "gap" else None
+            with span("wait", dispatch=number):
+                # one transfer: answers, certificate and gaps together
+                medoids, verified, gap = jax.device_get(
+                    (medoids, verified, gap))
+                if quantized and not verified.all():
                     # certificate failed for some slot: ONE exact fp32
                     # re-dispatch with the same key answers the whole
                     # batch; verified slots keep the (identical) quantized
                     # answer. Served answers are always fp32-exact.
                     fellback = True
-                    fout = ragged_medoids(
-                        data, lengths, sub, budget=budget,
-                        metric=self.metric, backend=self.backend,
-                        min_bucket=self.min_bucket, telemetry=False)
-                    medoids = jnp.where(verified, medoids, fout)
-            medoids = [int(m) for m in medoids]      # block until ready
+                    with span("dispatch", fallback=1, **ids):
+                        fout = ragged_medoids(
+                            data, lengths, sub, budget=budget,
+                            metric=self.metric, backend=self.backend,
+                            min_bucket=self.min_bucket, telemetry=False)
+                    medoids = np.where(verified, medoids,
+                                       jax.device_get(fout))
+                medoids = [int(m) for m in medoids]
         except Exception:
             # dispatch failed: requests go back to the head of the queue so
             # nothing is ever lost between `queue` and `done`
@@ -350,61 +385,69 @@ class MedoidServer:
         wall = time.time() - t0
         traced = ragged_compile_count() - compiles0
         self._recompiles += traced
-
-        # executed-round accounting (matches the facade and the telemetry
-        # rows; identical to schedule_pulls whenever the schedule ends at
-        # its output round, which round_schedule guarantees)
-        rounds = round_schedule(n_bucket, budget)
-        stop = stop_round(rounds)
-        pulls = sum(r.pulls for r in rounds[: stop + 1])
-        if self.precision != "fp32":
-            # the exact verification epilogue's distance evals, plus the
-            # full fp32 re-run when the certificate failed
-            pulls += quant.verify_pulls(n_bucket, rounds)
-            if fellback:
-                self.quant_fallbacks += 1
-                pulls += sum(r.pulls for r in rounds[: stop + 1])
-        self.dispatches += 1
-        self.buckets_seen.add(bkey)
-        finish = self._clock()
-        for slot, q in enumerate(batch):
-            q.medoid = medoids[slot]
-            q.wait_steps = self._step - q.submit_step - 1
-            q.batch_wall_s = round(wall, 4)
-            q.pulls = pulls
-            q.finish_s = finish
-            if q.deadline_s is not None:
-                q.deadline_met = finish <= q.deadline_s
-                self._metrics.record_deadline(label, q.deadline_met)
-            self.done[q.rid] = q
-        self._metrics.record_dispatch(
-            label, wall_s=wall, batch=len(batch), slots=self.max_batch,
-            pulls_per_request=pulls, waits=[q.wait_steps for q in batch],
-            compiled=traced > 0)
-        tel_host = telemetry_to_host(tel) if with_tel else None
-        if tel_host is not None and len(rounds):
-            # final executed round's winner gap per slot: the server's
-            # per-query hardness signal (NaN — fewer than two alive arms —
-            # is dropped by the histogram)
+        tel_host = None
+        if with_tel:
+            with span("gaps", dispatch=number):
+                tel_host = telemetry_to_host(tel)
+        with span("account", dispatch=number):
+            # executed-round accounting (matches the facade and the
+            # telemetry rows; identical to schedule_pulls whenever the
+            # schedule ends at its output round, which round_schedule
+            # guarantees)
+            rounds = round_schedule(n_bucket, budget)
+            stop = stop_round(rounds)
+            pulls = sum(r.pulls for r in rounds[: stop + 1])
+            if self.precision != "fp32":
+                # the exact verification epilogue's distance evals, plus
+                # the full fp32 re-run when the certificate failed
+                pulls += quant.verify_pulls(n_bucket, rounds)
+                if fellback:
+                    self.quant_fallbacks += 1
+                    pulls += sum(r.pulls for r in rounds[: stop + 1])
+            self.dispatches += 1
+            self.buckets_seen.add(bkey)
+            finish = self._clock()
             for slot, q in enumerate(batch):
-                q.gap = float(tel_host["gap"][slot, stop])
-                self._metrics.record_gap(label, q.gap)
-        if self.trace is not None:
-            self.trace.event("span", name="dispatch", dur_s=round(wall, 6),
-                             traces={"ragged": traced} if traced else {},
-                             dispatches={"ragged": 1}, bucket=label,
-                             batch=len(batch), step=self._step)
-            if fellback:
-                self.trace.event("quant_fallback", bucket=label,
-                                 precision=self.precision, step=self._step)
-            for slot, q in enumerate(batch):
-                # per-request rows: batched queries share the schedule
-                # columns but each slot's alive/theta/gap are its own
-                self.trace.record_rounds(tel_host, slot=slot, rid=q.rid,
-                                         bucket=label)
-                self.trace.event("select", winner=q.medoid, pulls=q.pulls,
-                                 n=q.n, rid=q.rid, bucket=label,
-                                 wait_steps=q.wait_steps)
+                q.medoid = medoids[slot]
+                q.wait_steps = self._step - q.submit_step - 1
+                q.batch_wall_s = round(wall, 4)
+                q.pulls = pulls
+                q.finish_s = finish
+                if q.deadline_s is not None:
+                    q.deadline_met = finish <= q.deadline_s
+                    self._metrics.record_deadline(label, q.deadline_met)
+                self.done[q.rid] = q
+            self._metrics.record_dispatch(
+                label, wall_s=wall, batch=len(batch), slots=self.max_batch,
+                pulls_per_request=pulls,
+                waits_s=[start_s - q.submit_s for q in batch],
+                compiled=traced > 0)
+            if len(rounds) and (tel_host is not None or gap is not None):
+                # output round's winner gap per slot: the server's
+                # per-query hardness signal (NaN — fewer than two alive
+                # arms — is dropped by the histogram)
+                for slot, q in enumerate(batch):
+                    q.gap = float(tel_host["gap"][slot, stop]
+                                  if tel_host is not None else gap[slot])
+                    self._metrics.record_gap(label, q.gap)
+            if self.trace is not None:
+                self.trace.event(
+                    "span", name="dispatch", dur_s=round(wall, 6),
+                    traces={"ragged": traced} if traced else {},
+                    dispatches={"ragged": 1}, bucket=label,
+                    batch=len(batch), step=self._step)
+                if fellback:
+                    self.trace.event("quant_fallback", bucket=label,
+                                     precision=self.precision,
+                                     step=self._step)
+                for slot, q in enumerate(batch):
+                    # per-request rows: batched queries share the schedule
+                    # columns but each slot's alive/theta/gap are its own
+                    self.trace.record_rounds(tel_host, slot=slot, rid=q.rid,
+                                             bucket=label)
+                    self.trace.event("select", winner=q.medoid,
+                                     pulls=q.pulls, n=q.n, rid=q.rid,
+                                     bucket=label, wait_steps=q.wait_steps)
         return batch
 
     def drain(self) -> dict[int, MedoidRequest]:

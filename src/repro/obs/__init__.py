@@ -5,8 +5,9 @@ Three layers, lowest first:
 * :mod:`repro.obs.telemetry` — the fixed-shape per-round telemetry pytree
   the engine carries through its banded ``lax.scan`` (device-path: pure jnp,
   host-sync-guarded alongside the engine package);
-* :mod:`repro.obs.trace` — :class:`TraceSession`, JSONL span/round/select
-  events + optional ``jax.profiler`` annotation hooks;
+* :mod:`repro.obs.trace` — :func:`span`, the ``medoid.<name>`` host span
+  on the profiler's clock, and :class:`TraceSession`, JSONL
+  span/round/select events built on it;
 * :mod:`repro.obs.metrics` — counters/histograms with a Prometheus text
   exposition, the :class:`ServerMetrics` bundle of the medoid server, and
   the engine-odometer exposition.
@@ -21,10 +22,11 @@ from __future__ import annotations
 from repro.obs import telemetry
 
 __all__ = ["MetricsRegistry", "ServerMetrics", "TraceSession",
-           "instrument_exposition", "telemetry", "telemetry_to_host"]
+           "instrument_exposition", "span", "telemetry", "telemetry_to_host"]
 
 _LAZY = {
     "TraceSession": ("repro.obs.trace", "TraceSession"),
+    "span": ("repro.obs.trace", "span"),
     "MetricsRegistry": ("repro.obs.metrics", "MetricsRegistry"),
     "ServerMetrics": ("repro.obs.metrics", "ServerMetrics"),
     "instrument_exposition": ("repro.obs.metrics", "instrument_exposition"),

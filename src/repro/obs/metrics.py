@@ -27,7 +27,9 @@ from typing import Iterable, Optional
 # Default latency buckets (seconds): spans sub-ms steady-state dispatches
 # through multi-second first-call compiles.
 LATENCY_BUCKETS_S = (0.001, 0.005, 0.02, 0.1, 0.5, 2.0, 10.0, 60.0)
-WAIT_BUCKETS_STEPS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0)
+# Queue-wait buckets (seconds): admission to the start of the answering
+# dispatch, from an idle server's sub-ms through a saturated queue's seconds.
+WAIT_BUCKETS_S = (0.001, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0)
 OCCUPANCY_BUCKETS = (0.25, 0.5, 0.75, 1.0)
 # Winner-gap buckets (distance units): final-round runner-up minus winner.
 # A near-zero gap is a *hard* query (halving barely separated the medoid);
@@ -221,8 +223,9 @@ class ServerMetrics:
             "scheduled distance evaluations charged to answered requests",
             ("bucket",))
         self.queue_wait = r.histogram(
-            "medoid_queue_wait_steps", "scheduler steps spent queued",
-            ("bucket",), buckets=WAIT_BUCKETS_STEPS)
+            "medoid_queue_wait_seconds",
+            "admission to the start of the answering dispatch (server clock)",
+            ("bucket",), buckets=WAIT_BUCKETS_S)
         self.occupancy = r.histogram(
             "medoid_batch_occupancy",
             "real requests / batch slots per dispatch",
@@ -259,14 +262,15 @@ class ServerMetrics:
 
     def record_dispatch(self, bucket: str, *, wall_s: float, batch: int,
                         slots: int, pulls_per_request: int,
-                        waits: Iterable[int], compiled: bool) -> None:
+                        waits_s: Iterable[float], compiled: bool) -> None:
         """Account one served batch: ``batch`` real requests in ``slots``
-        padded slots, ``compiled`` = this dispatch traced a new program."""
+        padded slots, ``waits_s`` their queue waits in seconds,
+        ``compiled`` = this dispatch traced a new program."""
         phase = "compile" if compiled else "steady"
         self.dispatches.labels(bucket, phase).inc()
         self.latency.labels(bucket, phase).observe(wall_s)
         self.occupancy.labels(bucket).observe(batch / max(1, slots))
-        for w in waits:
+        for w in waits_s:
             self.queue_wait.labels(bucket).observe(float(w))
         self.answered.labels(bucket).inc(batch)
         self.pulls.labels(bucket).inc(pulls_per_request * batch)

@@ -74,6 +74,14 @@ def round_stats(theta: jnp.ndarray) -> dict:
     }
 
 
+def winner_gap(theta: jnp.ndarray) -> jnp.ndarray:
+    """Runner-up minus incumbent of one round's masked estimates: the
+    ``gap`` of :func:`round_stats`, bit for bit, computed alone (the
+    serving path's per-query hardness signal for the output round)."""
+    st = jnp.sort(theta)
+    return (st[1] - st[0]).astype(jnp.float32)
+
+
 def schedule_constants(executed) -> dict:
     """The static (trace-time constant) telemetry columns for the executed
     rounds — scheduled survivor/reference/pull counts and the cumulative

@@ -1,4 +1,13 @@
-"""Structured trace events: JSONL spans + optional ``jax.profiler`` hooks.
+"""Structured trace events: profiler spans, JSONL records, profiler hooks.
+
+:func:`span` is the one host-span primitive: a
+``jax.profiler.TraceAnnotation`` named ``medoid.<name>`` with its ids as
+annotation metadata, so host phases land on the host plane of the same
+profiler trace as the device's ``XLA Ops``. It is entered identically
+whether or not a profiler is attached (Pallas kernels carry the Python
+frames they were traced under into their compiled program, so a span
+present only under a profiler would change the compile cache's key);
+with none attached it costs one C++ ``TraceMe`` that records nothing.
 
 A :class:`TraceSession` turns the engine's device-resident telemetry buffers
 (:mod:`repro.obs.telemetry`) and the trace/dispatch odometers
@@ -11,21 +20,17 @@ human (or the CI validator, :mod:`repro.obs.validate`) can read back:
     {"event": "select", "winner": 318, "pulls": 15402, ...}
 
 Every record carries ``event`` (its type), a monotone ``seq``, and a wall
-``ts``. Spans (``span(name)``) wrap host-side phases — trace, compile,
-dispatch, select — and record their duration plus the *deltas* of the engine
-odometers while the span was open (so ``traces > 0`` inside a dispatch span
-is exactly "this dispatch compiled something"). Round events are emitted
+``ts``. Session spans (``TraceSession.span(name)``, built on :func:`span`)
+wrap host-side phases — trace, compile, dispatch, select — and record their
+duration plus the *deltas* of the engine odometers while the span was open
+(so ``traces > 0`` inside a dispatch span is exactly "this dispatch compiled
+something"). Round events are emitted
 from a telemetry dict by :meth:`TraceSession.record_rounds`; their per-round
 ``pulls`` sum to the scheduled totals the facade reports, which the
 validator checks against the enclosing ``select`` event.
 
-Profiler integration (both off by default):
-
-* ``annotate=True`` wraps every span in a ``jax.profiler.TraceAnnotation``
-  of the same name, so bandit phases line up with XLA events in a
-  TensorBoard / Perfetto profile;
-* ``profiler_dir=...`` brackets the whole session in
-  ``jax.profiler.start_trace`` / ``stop_trace`` (written on ``close()``).
+``profiler_dir=...`` (off by default) brackets the whole session in
+``jax.profiler.start_trace`` / ``stop_trace`` (written on ``close()``).
 """
 from __future__ import annotations
 
@@ -35,9 +40,19 @@ import math
 import time
 from typing import IO, Optional
 
+import jax
+
 from repro.engine import instrument
 
 SCHEMA_VERSION = 1
+SPAN_PREFIX = "medoid."
+
+
+def span(name: str, **ids):
+    """Context manager: the host span ``medoid.<name>`` on the profiler's
+    clock, carrying ``ids`` (scalars or strings without commas) as its
+    metadata. Always entered; it records only while a profiler runs."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **ids)
 
 
 def _jsonable(v):
@@ -57,19 +72,17 @@ class TraceSession:
     """One JSONL trace stream (events also kept in memory for programmatic
     consumers). Usable as a context manager; ``close()`` is idempotent."""
 
-    def __init__(self, path: Optional[str] = None, *, annotate: bool = False,
-                 profiler_dir: Optional[str] = None, meta: Optional[dict] = None):
+    def __init__(self, path: Optional[str] = None, *,
+                 profiler_dir: Optional[str] = None,
+                 meta: Optional[dict] = None):
         self._fh: Optional[IO[str]] = open(path, "w") if path else None
         self.path = path
-        self.annotate = annotate
         self.profiler_dir = profiler_dir
         self.events: list[dict] = []
         self._seq = 0
         self._closed = False
         self._profiling = False
         if profiler_dir:
-            import jax
-
             jax.profiler.start_trace(profiler_dir)
             self._profiling = True
         self.event("session", version=SCHEMA_VERSION, **(meta or {}))
@@ -90,16 +103,11 @@ class TraceSession:
 
     @contextlib.contextmanager
     def span(self, name: str, **fields):
-        """Wrap a host-side phase: emits one ``span`` record on exit with
-        ``dur_s`` and the engine odometer deltas observed while open (plus a
-        ``jax.profiler.TraceAnnotation`` when ``annotate`` is set)."""
-        ann = contextlib.nullcontext()
-        if self.annotate:
-            import jax
-
-            ann = jax.profiler.TraceAnnotation(name)
+        """Wrap a host-side phase in :func:`span` (``fields`` as its ids) and
+        emit one ``span`` record on exit with ``dur_s`` and the engine
+        odometer deltas observed while open."""
         t0 = time.perf_counter()
-        with instrument.deltas() as d, ann:
+        with instrument.deltas() as d, span(name, **fields):
             yield
         self.event("span", name=name, dur_s=round(time.perf_counter() - t0, 6),
                    traces=d.counters()["traces"],
@@ -137,8 +145,6 @@ class TraceSession:
         self.event("session_end", events=self._seq)
         self._closed = True
         if self._profiling:
-            import jax
-
             jax.profiler.stop_trace()
             self._profiling = False
         if self._fh is not None:

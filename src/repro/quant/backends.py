@@ -234,4 +234,5 @@ register_backend(DistanceBackend(
     description="bf16 Gram centrality fused in the Pallas dot_centrality "
                 "kernel (in-kernel cast, fp32 accumulation)",
     fused_estimators=_BF16_FUSED,
+    tile=kops.TILE,
 ))

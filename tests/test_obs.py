@@ -239,11 +239,14 @@ def test_server_metrics_phase_split():
     m = ServerMetrics()
     m.record_submit("64x4")
     m.record_dispatch("64x4", wall_s=1.5, batch=2, slots=4,
-                      pulls_per_request=100, waits=[0, 1], compiled=True)
+                      pulls_per_request=100, waits_s=[0.0, 0.25],
+                      compiled=True)
     m.record_dispatch("64x4", wall_s=0.002, batch=4, slots=4,
-                      pulls_per_request=100, waits=[0, 0, 1, 2],
+                      pulls_per_request=100, waits_s=[0.0, 0.0, 0.25, 0.5],
                       compiled=False)
     snap = m.snapshot()
+    (wait,) = snap["medoid_queue_wait_seconds"]["series"]
+    assert (wait["count"], wait["sum"]) == (6, 1.0)
     series = {tuple(sorted(s["labels"].items())): s["value"]
               for s in snap["medoid_dispatches_total"]["series"]}
     assert series[(("bucket", "64x4"), ("phase", "compile"))] == 1

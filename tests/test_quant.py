@@ -262,9 +262,9 @@ def test_kmedoids_runs_on_quant_backend():
 
 
 def test_server_quant_warmup_pretraces_every_variant():
-    """The warmup satellite: a quantized server's warmup traces base +
-    telemetry quantized variants AND the exact fp32 fallback program, so
-    live traffic on warmed buckets never retraces."""
+    """The warmup satellite: a quantized server's warmup traces its live
+    quantized variant AND the exact fp32 fallback program, so live traffic
+    on warmed buckets never retraces."""
     from repro.launch.serve_medoid import MedoidServer
 
     srv = MedoidServer(precision="bf16", seed=0, max_batch=4)

@@ -432,8 +432,9 @@ class TestMedoidServer:
     def test_warmup_covers_both_program_variants(self):
         from repro.launch.serve_medoid import MedoidServer
 
-        # gap collection ON (the default): dispatches ride the telemetry
-        # variant — a warmed server's first metered step must not trace
+        # gap collection ON (the default): dispatches ride the plain
+        # program with its winner-gap output — the variant warmup traces —
+        # so a warmed server's first metered step must not trace
         srv = MedoidServer(budget_per_arm=8, max_batch=2)
         srv.warmup([(40, 6)])
         srv.submit(jax.random.normal(jax.random.key(3), (40, 6)))
