@@ -53,6 +53,7 @@ from typing import Callable, Mapping, Optional, Union
 import jax.numpy as jnp
 
 from repro.core import distances
+from repro.engine.instrument import Tile
 from repro.kernels import ops as kops
 
 PairwiseFn = Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
@@ -98,6 +99,11 @@ class DistanceBackend:
     # call to (``kops.TILE`` for the Pallas backends); ``None`` = no
     # padding. Feeds the engine's ``computed`` work tally.
     tile: Optional[tuple[int, int, int]] = None
+    # The block the fused centrality kernel pads a call to, by metric, where
+    # it differs from ``tile``: ``metric -> block or rule of the call's
+    # (rows, refs, width)`` (``kops.centrality_tile``: the ℓ1 kernel sizes
+    # its tile from the shape). ``None`` = ``tile``.
+    centrality_tile: Optional[Callable[[str], Tile]] = None
 
 
 _REGISTRY: dict[str, DistanceBackend] = {}
@@ -187,6 +193,7 @@ register_backend(DistanceBackend(
     description="fused in-kernel reference reduction (no (C, R) in HBM)",
     fused_estimators=_FUSED_ESTIMATORS,
     tile=kops.TILE,
+    centrality_tile=kops.centrality_tile,
 ))
 
 
@@ -211,4 +218,5 @@ register_backend(DistanceBackend(
     survivor_order=_order_epilogue,
     fused_estimators=_FUSED_ESTIMATORS,
     tile=kops.TILE,
+    centrality_tile=kops.centrality_tile,
 ))

@@ -55,6 +55,8 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.engine.instrument import Tile
+
 # NOTE: repro.core is imported lazily inside the factories — the engine
 # package sits BELOW repro.core in the layering (repro.core.__init__ pulls in
 # corr_sh, which is built on this package), so module-level imports here
@@ -71,10 +73,11 @@ ScoreFn = Callable[..., Tuple[jnp.ndarray, Any]]
 class ArmEstimator:
     """One arm-loss estimator: a name (for registries/telemetry) + score fn.
     ``tile`` is the (candidate, reference, width) block its kernel pads a
-    call to (``None``: no padding), for the engine's work tally."""
+    call to, or the rule that gives it from the call's shape (``None``: no
+    padding), for the engine's work tally."""
     name: str
     score: ScoreFn
-    tile: Optional[Tuple[int, int, int]] = None
+    tile: Optional[Tile] = None
 
 
 # ------------------------- estimator factory registry -----------------------
@@ -150,7 +153,8 @@ def medoid_centrality(backend=None, metric: str = "l2", *,
         fn = fused(metric) if fused is not None else be.centrality_sums(metric)
         plain = fn
         masked = _masked_centrality_fn(be, fn, metric)
-        tile = be.tile
+        tile = be.tile if be.centrality_tile is None \
+            else be.centrality_tile(metric)
 
     def score(cand, ref_rows, *, refs, ref_mask=None):
         if ref_mask is None:

@@ -25,7 +25,9 @@ open :func:`tally`; the program keeps that per-dispatch :class:`Work` by
 signature, and the host wrapper adds it on each dispatch
 (:func:`note_work`). ``called`` is the block the engine asks the estimator
 for (band width and reference buffer included); ``computed`` is the same
-block padded to the kernel's tile — what the kernel actually evaluates.
+block padded to the kernel's tile — what the kernel actually evaluates
+(a tile that the kernel sizes from the call's shape, as the ℓ1 centrality
+kernel does, is given as that rule).
 No device work, no host sync.
 """
 from __future__ import annotations
@@ -33,7 +35,12 @@ from __future__ import annotations
 import contextlib
 from collections import Counter
 from dataclasses import dataclass
-from typing import Optional
+from typing import Callable, Optional, Union
+
+# A kernel's (row, reference, width) block, fixed or a rule of the call's
+# (rows, refs, width).
+Block = tuple[int, int, int]
+Tile = Union[Block, Callable[[int, int, int], Block]]
 
 _TRACES: Counter = Counter()
 _DISPATCHES: Counter = Counter()
@@ -55,17 +62,20 @@ def _padded(size: int, block: int) -> int:
 
 
 def note_score(rows: int, refs: int, width: int, *, runs: int = 1,
-               tile: Optional[tuple[int, int, int]] = None) -> None:
+               tile: Optional[Tile] = None) -> None:
     """Record one estimator call over a ``(rows, width) x (refs, width)``
     block that runs ``runs`` times per program run (call at trace time,
     outside any scan body: a scan body is traced once but runs once per
     scanned round). ``tile`` = (row, reference, width) block of the kernel
-    that evaluates it; ``None`` = no padding. Does nothing outside a
+    that evaluates it, or the rule that gives that block for the call's
+    ``(rows, refs, width)``; ``None`` = no padding. Does nothing outside a
     :func:`tally`."""
     if not _TALLIES:
         return
     work, copies = _TALLIES[-1]
     called = rows * refs * width
+    if callable(tile):
+        tile = tile(rows, refs, width)
     computed = called if tile is None else (
         _padded(rows, tile[0]) * _padded(refs, tile[1])
         * _padded(width, tile[2]))

@@ -16,8 +16,9 @@ import jax.numpy as jnp
 
 from repro.kernels import pairwise_distance as pk
 
-# The (candidate, reference, width) block every wrapper below pads to: the
-# shape of the work a kernel call actually evaluates.
+# The (candidate, reference, width) block every wrapper below pads to, save
+# the fused ℓ1 centrality, whose block follows the call's shape
+# (``centrality_tile``): the shape of the work a kernel call evaluates.
 TILE = (pk.BC, pk.BR, pk.BD)
 
 
@@ -75,6 +76,29 @@ def _pad_ref_mask(ref_mask: jnp.ndarray | None, r: int,
     return m
 
 
+def centrality_tile(metric: str):
+    """The block the fused centrality kernel of ``metric`` pads a call to:
+    a rule of the call's ``(rows, refs, width)`` for ℓ1, whose VPU kernel
+    sizes its tile from the shape, and the fixed ``TILE`` of the MXU
+    kernel for the Gram metrics."""
+    return pk.l1_centrality_tile if metric == "l1" else TILE
+
+
+def _l1_centrality_sums(x: jnp.ndarray, y: jnp.ndarray,
+                        ref_mask: jnp.ndarray | None,
+                        interpret: bool) -> jnp.ndarray:
+    """(C,) ℓ1 distance sums over the valid references, through the fused
+    kernel at the block ``pk.l1_centrality_tile`` gives the call. Inputs
+    stream as f32, whose sublane multiple (8) the rule pads rows to."""
+    (c, d), r = x.shape, y.shape[0]
+    bc, br, bd = block = pk.l1_centrality_tile(c, r, d)
+    xp = _pad_to(x.astype(jnp.float32), bc, bd)
+    yp = _pad_to(y.astype(jnp.float32), br, bd)
+    mask = _pad_ref_mask(ref_mask, r, yp.shape[0])
+    return pk.l1_centrality(xp, yp, r_true=r, block=block, ref_mask=mask,
+                            interpret=interpret)[:c, 0]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kernel_l1_centrality(x: jnp.ndarray, y: jnp.ndarray,
                          interpret: bool | None = None,
@@ -84,14 +108,10 @@ def kernel_l1_centrality(x: jnp.ndarray, y: jnp.ndarray,
     With ``ref_mask`` (shape (R,), nonzero = valid) the mean runs over the
     valid references only."""
     interp = interpret_mode() if interpret is None else interpret
-    c, r = x.shape[0], y.shape[0]
-    xp = _pad_to(x, pk.BC, pk.BD)
-    yp = _pad_to(y, pk.BR, pk.BD)
-    mask = _pad_ref_mask(ref_mask, r, yp.shape[0])
-    sums = pk.l1_centrality(xp, yp, r_true=r, ref_mask=mask,
-                            interpret=interp)[:c, 0]
-    denom = r if ref_mask is None else jnp.maximum(jnp.sum(mask), 1.0)
-    return sums / denom
+    sums = _l1_centrality_sums(x, y, ref_mask, interp)
+    if ref_mask is None:
+        return sums / y.shape[0]
+    return sums / jnp.maximum(jnp.sum(ref_mask.astype(jnp.float32)), 1.0)
 
 
 def _norms_sq(a: jnp.ndarray) -> jnp.ndarray:
@@ -147,11 +167,7 @@ def kernel_centrality_sums(x: jnp.ndarray, y: jnp.ndarray, *,
     interp = interpret_mode() if interpret is None else interpret
     c, r = x.shape[0], y.shape[0]
     if metric == "l1":
-        xp = _pad_to(x, pk.BC, pk.BD)
-        yp = _pad_to(y, pk.BR, pk.BD)
-        mask = _pad_ref_mask(ref_mask, r, yp.shape[0])
-        return pk.l1_centrality(xp, yp, r_true=r, ref_mask=mask,
-                                interpret=interp)[:c, 0]
+        return _l1_centrality_sums(x, y, ref_mask, interp)
     if metric == "cosine":
         xf, yf = _unit_rows(x), _unit_rows(y)
         xn2 = jnp.zeros((c, 1), jnp.float32)   # unused by the cosine path

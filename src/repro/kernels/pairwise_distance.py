@@ -9,17 +9,19 @@ metrics into two kernel families:
   computed outside the kernel (Gram trick), so the inner loop runs on the
   128x128 systolic array at full rate.
 
-* **ℓ1 kernels** (VPU path): ``sum |x - y|`` has no matmul form. The kernel
-  tiles ``(BC, BD) x (BD, BR)`` (the reference block transposed) into VMEM
-  and accumulates f32 partial sums in a lane-dense (BC, BR) tile, one
-  d-column at a time. Two variants:
-    - ``l1_pairwise``  -> (C, R) distance matrix
-    - ``l1_centrality``-> fused row-sum (C,): never materializes (C, R) in HBM,
-      which is the memory-roofline win for large reference sets.
+* **ℓ1 kernels** (VPU path): ``sum |x - y|`` has no matmul form. Two
+  variants:
+    - ``l1_pairwise``  -> (C, R) distance matrix. It tiles
+      ``(BC, BD) x (BD, BR)`` (the reference block transposed) into VMEM and
+      accumulates a lane-dense (BC, BR) tile, one d-column at a time.
+    - ``l1_centrality``-> fused row-sum (C,): never materializes (C, R) in
+      HBM. Both operands keep d on lanes, and its tile is sized from each
+      call's shape (``l1_centrality_tile``), since the round loop makes one
+      of its two row axes small in every call.
 
 Grid layout: (i, j, k) with k (the d-axis) innermost so each output tile is
 revisited across k steps and accumulated in place (standard Pallas reduction
-pattern); the fused centrality kernel also folds j into the accumulation.
+pattern); the fused centrality kernels also fold j into the accumulation.
 
 All wrappers in ``ops.py`` pad shapes to block multiples; padded d-columns are
 zeros (contribute 0 to every metric), padded candidate rows are sliced off,
@@ -38,10 +40,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Block sizes: MXU-aligned (multiples of 128 in the matmul dims). The ℓ1 VPU
-# kernels use the same tiles: per grid step an X tile and a transposed Y tile
-# of BC*BD*4B = 128 KiB each (f32), plus one (BC, BR) f32 accumulator of
-# 64 KiB — under 1 MiB of VMEM with double-buffered inputs.
+# Block sizes: MXU-aligned (multiples of 128 in the matmul dims). The ℓ1
+# pairwise kernel uses the same tiles: per grid step an X tile and a
+# transposed Y tile of BC*BD*4B = 128 KiB each (f32), plus one (BC, BR) f32
+# accumulator of 64 KiB — under 1 MiB of VMEM with double-buffered inputs.
+# The fused ℓ1 centrality kernel sizes its own (``l1_centrality_tile``).
 BC = 128   # candidate rows per tile
 BR = 128   # reference rows per tile
 BD = 256   # d-axis slab per grid step
@@ -150,50 +153,127 @@ def l1_pairwise(x: jnp.ndarray, y: jnp.ndarray, *,
 
 
 # --------------------------------------------------------------------------
-# fused ℓ1 centrality kernel: S[c] = sum_{r valid} sum_d |X[c,d] - Y[r,d]|
-# Never materializes the (C, R) matrix in HBM. Validity is a streamed (1, R)
-# f32 mask row (1.0 = count this reference), which covers both block padding
-# and the ragged engine's invalid (padded-arm) references; it weights the
-# slab's (BC, BR) tile before the lane reduction into the (BC, 1) output.
+# fused ℓ1 centrality kernel: S[c] = sum_{r valid} w_r sum_d |X[c,d] - Y[r,d]|
+# Never materializes the (C, R) matrix in HBM. Unlike the pairwise kernels,
+# both operands stream in their natural row-major layout with d on lanes:
+# a (bc, bd) candidate tile and a (br, bd) reference tile. Per reference,
+# its row is broadcast over sublanes and |X - y_r| is summed over the
+# slab's 128-lane chunks into a lane-dense (bc, 128) f32 partial, so the
+# inner loop is one sub, one abs and one add per vreg with no cross-lane
+# broadcast. The partial is weighted by the reference's validity w_r (a
+# streamed (br, 1) column: block padding and the ragged engine's invalid
+# references weigh 0) into the grid step's own (bc, 128) sum, which folds
+# into a VMEM accumulator; its lane reduction to (bc, 1) runs once, at the
+# candidate tile's last step.
+#
+# The tile follows the call's shape (``l1_centrality_tile``): the round
+# loop makes one axis of every call small (few references early, few
+# candidates late), so each row axis pads only to the sublane multiple.
 # --------------------------------------------------------------------------
 
-def _l1_centrality_kernel(x_ref, yt_ref, m_ref, o_ref):
-    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+SUBLANES, LANES = 8, 128
+L1_ROWS = 128            # candidate rows per tile: a (128, 128) f32 partial
+                         # is 16 vregs, and the step's sum 16 more
+L1_REFS = 512            # reference rows per tile
+L1_TILE_BYTES = 8 << 20  # both input tiles, double-buffered, f32
 
-    a = _l1_slab(x_ref, yt_ref) * m_ref[...]            # mask invalid refs
-    o_ref[...] += jnp.sum(a, axis=1, keepdims=True)     # (BC, 1)
+
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _even_block(n: int, cap: int) -> int:
+    """The least sublane-multiple block, at most ``cap``, that covers ``n``
+    rows in as few equal tiles as ``cap`` allows."""
+    return _cdiv(_cdiv(n, _cdiv(n, cap)), SUBLANES) * SUBLANES
+
+
+def l1_centrality_tile(c: int, r: int, d: int) -> tuple[int, int, int]:
+    """(candidate, reference, width) block of the fused ℓ1 kernel for a
+    ``(c, d) x (r, d)`` call: a pure function of the static shape.
+
+    Rows pad to the sublane multiple (8) in equal tiles of at most
+    ``L1_ROWS`` candidates and ``L1_REFS`` references. The width block is
+    the widest multiple of 128 whose double-buffered tiles fit
+    ``L1_TILE_BYTES`` and that pads ``d`` by at most 1% (or one 128-lane
+    chunk past its lane rounding, where 1% is less than that).
+    """
+    bc = _even_block(max(c, 1), L1_ROWS)
+    br = _even_block(max(r, 1), L1_REFS)
+    chunks = _cdiv(max(d, 1), LANES)
+    most = max(1, min(chunks, L1_TILE_BYTES // (2 * 4 * (bc + br) * LANES)))
+    slack = max(d // 100, (chunks + 1) * LANES - d)
+    q = max(q for q in range(1, most + 1)
+            if _cdiv(chunks, q) * q * LANES - d <= slack)
+    return bc, br, q * LANES
+
+
+def _l1_centrality_kernel(x_ref, y_ref, w_ref, o_ref, acc_ref, wl_ref):
+    j = pl.program_id(1)
+    k = pl.program_id(2)
+    bc, bd = x_ref.shape
+
+    @pl.when((j == 0) & (k == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # the weights, broadcast over lanes once a step
+    wl_ref[...] = jnp.broadcast_to(w_ref[...], wl_ref.shape)
+
+    def group(g, step):
+        # references load in aligned groups of 8 rows; each row is then
+        # broadcast over the candidate tile's sublanes
+        r0 = pl.multiple_of(g * SUBLANES, SUBLANES)
+        w = wl_ref[pl.ds(r0, SUBLANES), :]
+        for rr in range(SUBLANES):
+            part = jnp.zeros((bc, LANES), jnp.float32)
+            for lo in range(0, bd, LANES):     # static unroll over the slab
+                y = y_ref[pl.ds(r0, SUBLANES), lo:lo + LANES]
+                part = part + jnp.abs(x_ref[:, lo:lo + LANES] - y[rr:rr + 1])
+            step = step + part * w[rr:rr + 1]
+        return step
+
+    acc_ref[...] += jax.lax.fori_loop(
+        0, y_ref.shape[0] // SUBLANES, group,
+        jnp.zeros((bc, LANES), jnp.float32))
+
+    @pl.when((j == pl.num_programs(1) - 1) & (k == pl.num_programs(2) - 1))
+    def _finish():
+        o_ref[...] = jnp.sum(acc_ref[...], axis=1, keepdims=True)
 
 
 def l1_centrality(x: jnp.ndarray, y: jnp.ndarray, r_true: int, *,
+                  block: tuple[int, int, int],
                   ref_mask: jnp.ndarray | None = None,
                   interpret: bool = False) -> jnp.ndarray:
     """Row sums of |X - Y| distances over the valid rows of Y.
 
-    x: (C, d), y: (R, d) padded; returns (C, 1) f32 sums (not yet divided).
-    By default the first ``r_true`` rows are valid; ``ref_mask`` (any shape
-    broadcastable to (R,), nonzero = valid, already combined with the padding
-    prefix by the caller or here) overrides the prefix predicate.
+    x: (C, d), y: (R, d), both padded to multiples of ``block`` (the
+    ``l1_centrality_tile`` of the unpadded call); returns (C, 1) f32 sums
+    (not yet divided). By default the first ``r_true`` rows are valid;
+    ``ref_mask`` (any shape broadcastable to (R,), a multiplicative weight,
+    nonzero = valid) further restricts them.
     """
     c, d = x.shape
     r, _ = y.shape
+    bc, br, bd = block
     mask = (jnp.arange(r) < r_true).astype(jnp.float32)
     if ref_mask is not None:
         mask = mask * ref_mask.reshape(-1).astype(jnp.float32)
-    grid = (c // BC, r // BR, d // BD)
     return pl.pallas_call(
         _l1_centrality_kernel,
-        grid=grid,
+        grid=(c // bc, r // br, d // bd),
         in_specs=[
-            pl.BlockSpec((BC, BD), lambda i, j, k: (i, k)),
-            pl.BlockSpec((BD, BR), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, BR), lambda i, j, k: (0, j)),
+            pl.BlockSpec((bc, bd), lambda i, j, k: (i, k)),
+            pl.BlockSpec((br, bd), lambda i, j, k: (j, k)),
+            pl.BlockSpec((br, 1), lambda i, j, k: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((BC, 1), lambda i, j, k: (i, 0)),
+        out_specs=pl.BlockSpec((bc, 1), lambda i, j, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((c, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bc, LANES), jnp.float32),
+                        pltpu.VMEM((br, LANES), jnp.float32)],
         interpret=interpret,
-    )(x, y.T, mask.reshape(1, r))
+    )(x, y, mask.reshape(r, 1))
 
 
 # --------------------------------------------------------------------------

@@ -235,4 +235,5 @@ register_backend(DistanceBackend(
                 "kernel (in-kernel cast, fp32 accumulation)",
     fused_estimators=_BF16_FUSED,
     tile=kops.TILE,
+    centrality_tile=kops.centrality_tile,
 ))

@@ -3,9 +3,11 @@
 Interpret mode runs the Pallas kernels through XLA:CPU and cannot see what
 the TPU compiler refuses: 1-D vector layouts, lanes padded past the scoped
 VMEM limit, unaligned slices. These tests compile the kernels at
-1024x512x1024 tiles and the l1 ``pallas_fused`` medoid program at 4096x512
-for a described ``v5e:2x2`` topology, and check that the compiled program
-holds the Mosaic kernel (``tpu_custom_call``) rather than an interpreted one.
+1024x512x1024 tiles, the fused l1 kernel at both extremes of its
+shape-sized tile at RNA-Seq width, and the l1 ``pallas_fused`` medoid
+program at 4096x512 for a described ``v5e:2x2`` topology, and check that
+the compiled program holds the Mosaic kernel (``tpu_custom_call``) rather
+than an interpreted one.
 
 The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and every test worker imports
@@ -76,6 +78,19 @@ def test_l1_centrality_compiles(compiled_kernels, one_chip):
     x = _spec((C, D), jnp.float32, one_chip)
     y = _spec((R, D), jnp.float32, one_chip)
     mask = _spec((R,), jnp.float32, one_chip)
+    _compile(lambda a, b, m: ops.kernel_centrality_sums(
+        a, b, metric="l1", ref_mask=m), x, y, mask)
+
+
+@pytest.mark.parametrize("c,r", [(8, 8192), (4096, 8)])
+def test_l1_centrality_compiles_at_both_extreme_tilings(compiled_kernels,
+                                                        one_chip, c, r):
+    """RNA-Seq width (d = 27,998): few candidates against many references
+    (the late rounds) and the reverse (the early ones), each at the tile
+    its shape gives, inside the scoped VMEM limit."""
+    x = _spec((c, 27998), jnp.float32, one_chip)
+    y = _spec((r, 27998), jnp.float32, one_chip)
+    mask = _spec((r,), jnp.float32, one_chip)
     _compile(lambda a, b, m: ops.kernel_centrality_sums(
         a, b, metric="l1", ref_mask=m), x, y, mask)
 

@@ -168,38 +168,41 @@ def test_gaps_on_server_never_runs_the_telemetry_variant(monkeypatch):
 
 # -------------------------------- work tally ---------------------------------
 
-def _hand_count(tile):
+def _hand_count(padded):
     """n = 257, 16 pulls per arm, d = 40: the schedule keeps 257, 129, 65,
     33, 17, 9, 5, 3, 2 arms against 1, 3, 7, 13, 26, 50, 91, 152, 228
     references; rounds 0-7 run as three scan bands of widths 257, 33, 5
     (reference buffers 7, 50, 152; 3, 3 and 2 trips) and round 8 is the
-    output round, 2 arms x 228 references."""
+    output round, 2 arms x 228 references. The fused l1 kernel pads each
+    row axis to the sublane multiple (8) in equal tiles of at most 128
+    candidates and 512 references (257 -> 3 x 88), and d = 40 to one
+    128-lane chunk."""
     blocks = [(257, 7, 3), (33, 50, 3), (5, 152, 2), (2, 228, 1)]
 
     def pad(v, b):
         return -(-v // b) * b
 
+    def rows(v, cap):
+        tiles = -(-v // cap)
+        return tiles * pad(-(-v // tiles), 8)
+
     called = sum(r * t * trips for r, t, trips in blocks) * 40
-    if tile is None:
+    if not padded:
         return called, called
-    bc, br, bd = tile
-    computed = sum(pad(r, bc) * pad(t, br) * trips
-                   for r, t, trips in blocks) * pad(40, bd)
+    computed = sum(rows(r, 128) * rows(t, 512) * trips
+                   for r, t, trips in blocks) * pad(40, 128)
     return called, computed
 
 
 @pytest.mark.parametrize("backend", ["reference", "pallas_fused"])
 def test_work_tally_matches_a_hand_count(backend):
-    from repro.kernels import ops as kops
-
     x = jax.random.normal(jax.random.key(8), (257, 40))
     with instrument.deltas() as d:
         for i in range(2):
             find_medoid(x, jax.random.key(i), metric="l1",
                         budget_per_arm=16, backend=backend)
-    called, computed = _hand_count(kops.TILE if backend != "reference"
-                                   else None)
-    assert (called, computed) == ((492920, 75497472)
+    called, computed = _hand_count(backend != "reference")
+    assert (called, computed) == ((492920, 2220032)
                                   if backend != "reference"
                                   else (492920, 492920))
     work = d.work("medoid")
