@@ -51,7 +51,10 @@ def netflix_like(key, n: int, d: int = 2048, radial: float = 1.2
     """Sparse nonnegative 'ratings' (cosine): a dominant taste direction with
     per-user angular spread, plus Zipf item popularity x per-user activity
     driving the (correlated) sparsity pattern — β_j here is the reference
-    user's angle/activity. Measured: ~8% density, rho_near ~ 0.32."""
+    user's angle/activity. Density falls with the width, as popularity
+    decays along the titles. Measured on 1,000-2,000 rows (seeds 0-2, 7):
+    3.0-3.1% at the default d = 2048 (median 33-35 ratings a user), 0.53%
+    at the Netflix Prize's 17,770 titles (median 48); rho_near ~ 0.32."""
     ku, kn, ke, ks, ka = jax.random.split(key, 5)
     u0 = jax.nn.relu(jax.random.normal(ku, (1, d))) + 0.1
     r = jnp.exp(jax.random.normal(ke, (n,)) * radial) * 0.5

@@ -74,10 +74,12 @@ class ArmEstimator:
     """One arm-loss estimator: a name (for registries/telemetry) + score fn.
     ``tile`` is the (candidate, reference, width) block its kernel pads a
     call to, or the rule that gives it from the call's shape (``None``: no
-    padding), for the engine's work tally."""
+    padding), and ``norms`` whether each call takes the norm of every
+    operand row first (the Gram metrics), for the engine's work tally."""
     name: str
     score: ScoreFn
     tile: Optional[Tile] = None
+    norms: bool = False
 
 
 # ------------------------- estimator factory registry -----------------------
@@ -128,6 +130,12 @@ def _masked_centrality_fn(be, fn, metric: str) -> Callable:
 
 # ----------------------------- built-in factories ---------------------------
 
+def _takes_norms(metric: str) -> bool:
+    """Whether ``metric``'s distance path takes row norms on every call:
+    cosine's unit rows and the squared norms of ℓ2 and squared ℓ2."""
+    return metric in ("l2", "sql2", "cosine")
+
+
 def medoid_centrality(backend=None, metric: str = "l2", *,
                       pairwise_fn: Optional[Callable] = None) -> ArmEstimator:
     """The paper's estimator: ``sum_j d(x_i, y_j)``.
@@ -161,7 +169,9 @@ def medoid_centrality(backend=None, metric: str = "l2", *,
             return plain(cand, ref_rows), None
         return masked(cand, ref_rows, ref_mask), None
 
-    return ArmEstimator("medoid_centrality", score, tile)
+    # a caller's own pairwise_fn takes whatever norms it takes, unseen
+    return ArmEstimator("medoid_centrality", score, tile,
+                        pairwise_fn is None and _takes_norms(metric))
 
 
 def build_delta(backend=None, metric: str = "l2", *,
@@ -187,7 +197,7 @@ def build_delta(backend=None, metric: str = "l2", *,
             blk = jnp.minimum(pw(cand, ref_rows), d1[refs][None, :])
             return distances.masked_rowsum(blk, ref_mask), None
 
-    return ArmEstimator("build_delta", score, be.tile)
+    return ArmEstimator("build_delta", score, be.tile, _takes_norms(metric))
 
 
 def swap_delta(backend=None, metric: str = "l2", *, d1: jnp.ndarray,
@@ -230,7 +240,7 @@ def swap_delta(backend=None, metric: str = "l2", *, d1: jnp.ndarray,
                      + term @ onehot)                         # (C, k)
             return jnp.min(delta, axis=1), delta
 
-    return ArmEstimator("swap_delta", score, be.tile)
+    return ArmEstimator("swap_delta", score, be.tile, _takes_norms(metric))
 
 
 register_estimator("medoid_centrality", medoid_centrality)

@@ -255,7 +255,7 @@ def _scan_band(problem: HalvingProblem, band: StackedBand, order_fn: OrderFn,
         return (key, buf), ys
 
     instrument.note_score(width, cap, data.shape[1], runs=len(band),
-                          tile=est.tile)
+                          tile=est.tile, norms=est.norms)
     (key, buf), rows = jax.lax.scan(body, (key, buf), xs)
     return key, buf, rows
 
@@ -314,7 +314,7 @@ def _scan_band_widened(problem: HalvingProblem, band: StackedBand,
         return (key, buf, live), ys
 
     instrument.note_score(width, cap, data.shape[1], runs=len(band),
-                          tile=est.tile)
+                          tile=est.tile, norms=est.norms)
     (key, buf, live), rows = jax.lax.scan(body, (key, buf, live), xs)
     return key, buf, live, rows
 
@@ -361,7 +361,7 @@ def _run_halving_widened(problem: HalvingProblem, sched, order_fn: OrderFn,
         ref_mask = None
         denom = refs.shape[0]              # static Python int
     instrument.note_score(survivors.shape[0], refs.shape[0], data.shape[1],
-                          tile=est.tile)
+                          tile=est.tile, norms=est.norms)
     sums, aux = est.score(data[survivors], data[refs], refs=refs,
                           ref_mask=ref_mask)
     theta = sums / denom
@@ -460,7 +460,7 @@ def run_halving(problem: HalvingProblem, schedule: Sequence[Round],
         ref_mask = None
         denom = refs.shape[0]              # static Python int
     instrument.note_score(survivors.shape[0], refs.shape[0], data.shape[1],
-                          tile=est.tile)
+                          tile=est.tile, norms=est.norms)
     sums, aux = est.score(data[survivors], data[refs], refs=refs,
                           ref_mask=ref_mask)
     theta = sums / denom

@@ -27,7 +27,9 @@ signature, and the host wrapper adds it on each dispatch
 for (band width and reference buffer included); ``computed`` is the same
 block padded to the kernel's tile — what the kernel actually evaluates
 (a tile that the kernel sizes from the call's shape, as the ℓ1 centrality
-kernel does, is given as that rule).
+kernel does, is given as that rule). ``normed`` counts the operand rows
+whose norms a Gram metric takes before the kernel on every call (cosine's
+unit rows, ℓ2's squared norms; none for ℓ1): ``rows + refs`` per call.
 No device work, no host sync.
 """
 from __future__ import annotations
@@ -46,15 +48,19 @@ _TRACES: Counter = Counter()
 _DISPATCHES: Counter = Counter()
 _CALLED: Counter = Counter()
 _COMPUTED: Counter = Counter()
+_NORMED: Counter = Counter()
+_WORK = (_CALLED, _COMPUTED, _NORMED)   # the fields of Work, in order
 _TALLIES: list = []          # open trace-time (Work, copies) collectors
 
 
 @dataclass
 class Work:
     """Distance terms of one dispatch: asked for (``called``) and evaluated
-    after tile padding (``computed``)."""
+    after tile padding (``computed``); operand rows whose norms the calls
+    take (``normed``)."""
     called: int = 0
     computed: int = 0
+    normed: int = 0
 
 
 def _padded(size: int, block: int) -> int:
@@ -62,13 +68,14 @@ def _padded(size: int, block: int) -> int:
 
 
 def note_score(rows: int, refs: int, width: int, *, runs: int = 1,
-               tile: Optional[Tile] = None) -> None:
+               tile: Optional[Tile] = None, norms: bool = False) -> None:
     """Record one estimator call over a ``(rows, width) x (refs, width)``
     block that runs ``runs`` times per program run (call at trace time,
     outside any scan body: a scan body is traced once but runs once per
     scanned round). ``tile`` = (row, reference, width) block of the kernel
     that evaluates it, or the rule that gives that block for the call's
-    ``(rows, refs, width)``; ``None`` = no padding. Does nothing outside a
+    ``(rows, refs, width)``; ``None`` = no padding. ``norms``: the call
+    takes the norm of every operand row first. Does nothing outside a
     :func:`tally`."""
     if not _TALLIES:
         return
@@ -81,6 +88,8 @@ def note_score(rows: int, refs: int, width: int, *, runs: int = 1,
         * _padded(width, tile[2]))
     work.called += copies * runs * called
     work.computed += copies * runs * computed
+    if norms:
+        work.normed += copies * runs * (rows + refs)
 
 
 @contextlib.contextmanager
@@ -101,12 +110,15 @@ def note_work(kind: str, work: Work) -> None:
     side, next to :func:`note_dispatch`)."""
     _CALLED[kind] += work.called
     _COMPUTED[kind] += work.computed
+    _NORMED[kind] += work.normed
 
 
 def work_counters() -> dict:
-    """Snapshot of the work odometer (per kind, in distance terms)."""
+    """Snapshot of the work odometer (per kind: distance terms, and rows
+    normed)."""
     return {"called": dict(sorted(_CALLED.items())),
-            "computed": dict(sorted(_COMPUTED.items()))}
+            "computed": dict(sorted(_COMPUTED.items())),
+            "normed": dict(sorted(_NORMED.items()))}
 
 
 def note_trace(kind: str) -> None:
@@ -156,14 +168,14 @@ class deltas:
     def __enter__(self) -> "deltas":
         self._t0 = Counter(_TRACES)
         self._d0 = Counter(_DISPATCHES)
-        self._w0 = (Counter(_CALLED), Counter(_COMPUTED))
+        self._w0 = tuple(map(Counter, _WORK))
         self._t1 = self._d1 = self._w1 = None
         return self
 
     def __exit__(self, *exc) -> None:
         self._t1 = Counter(_TRACES)
         self._d1 = Counter(_DISPATCHES)
-        self._w1 = (Counter(_CALLED), Counter(_COMPUTED))
+        self._w1 = tuple(map(Counter, _WORK))
 
     def _now(self) -> tuple[Counter, Counter]:
         if self._t1 is not None:
@@ -186,9 +198,8 @@ class deltas:
 
     def work(self, kind: str) -> Work:
         """Distance work dispatched since enter by the ``kind`` programs."""
-        called, computed = self._w1 or (_CALLED, _COMPUTED)
-        return Work(called[kind] - self._w0[0][kind],
-                    computed[kind] - self._w0[1][kind])
+        now = self._w1 or _WORK
+        return Work(*(c[kind] - c0[kind] for c, c0 in zip(now, self._w0)))
 
     def counters(self) -> dict:
         """Per-kind nonzero deltas, same shape as the module snapshot."""
