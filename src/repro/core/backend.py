@@ -32,7 +32,9 @@ backends:
     Fused centrality kernels: the ℓ1 VPU kernel and the MXU
     ``dot_centrality`` kernel reduce over references *inside* the kernel —
     no round ever materializes the (s_r, t_r) block in HBM, for any metric.
-    This is the memory-roofline-optimal production path.
+    This is the memory-roofline-optimal production path. Under ℓ1 the
+    kernel fetches the round's rows from the corpus by index
+    (``centrality_sums_at``), so the round loop gathers none.
 
 ``pallas_fused_topk``
     ``pallas_fused`` plus the fused top-k survivor-selection epilogue
@@ -104,6 +106,13 @@ class DistanceBackend:
     # (rows, refs, width)`` (``kops.centrality_tile``: the ℓ1 kernel sizes
     # its tile from the shape). ``None`` = ``tile``.
     centrality_tile: Optional[Callable[[str], Tile]] = None
+    # Optional fused centrality that fetches its rows by index from the
+    # resident corpus: ``metric -> None or (data -> None or ((cand_idx,
+    # ref_idx, ref_mask=None) -> (C,) sums, block rule))``. The inner
+    # factory lays the corpus out once per run (None where the kernel
+    # cannot take it), and the round loop then copies no rows out of it.
+    # ``kops.centrality_kernel_at``: ℓ1 only. ``None`` = gathered rows.
+    centrality_sums_at: Optional[Callable[[str], Optional[Callable]]] = None
 
 
 _REGISTRY: dict[str, DistanceBackend] = {}
@@ -194,6 +203,7 @@ register_backend(DistanceBackend(
     fused_estimators=_FUSED_ESTIMATORS,
     tile=kops.TILE,
     centrality_tile=kops.centrality_tile,
+    centrality_sums_at=kops.centrality_kernel_at,
 ))
 
 
@@ -219,4 +229,5 @@ register_backend(DistanceBackend(
     fused_estimators=_FUSED_ESTIMATORS,
     tile=kops.TILE,
     centrality_tile=kops.centrality_tile,
+    centrality_sums_at=kops.centrality_kernel_at,
 ))

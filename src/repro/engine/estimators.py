@@ -75,11 +75,16 @@ class ArmEstimator:
     ``tile`` is the (candidate, reference, width) block its kernel pads a
     call to, or the rule that gives it from the call's shape (``None``: no
     padding), and ``norms`` whether each call takes the norm of every
-    operand row first (the Gram metrics), for the engine's work tally."""
+    operand row first (the Gram metrics), for the engine's work tally.
+    ``score_at``, where set, is the backend's ``centrality_sums_at``: the
+    same sums by index into the corpus, bitwise those of ``score`` on the
+    gathered rows at its own block, and the round loop then copies no rows
+    out of the corpus."""
     name: str
     score: ScoreFn
     tile: Optional[Tile] = None
     norms: bool = False
+    score_at: Optional[Callable] = None
 
 
 # ------------------------- estimator factory registry -----------------------
@@ -141,14 +146,16 @@ def medoid_centrality(backend=None, metric: str = "l2", *,
     """The paper's estimator: ``sum_j d(x_i, y_j)``.
 
     Uses the backend's fused path when registered (``fused_estimators`` or
-    the fused ``centrality_sums`` kernels). ``pairwise_fn`` overrides the
-    distance block directly (the legacy hook of
-    ``correlated_sequential_halving``; takes precedence over ``backend``).
+    the fused ``centrality_sums`` kernels), and scores by index where the
+    backend's kernel fetches its own rows (``centrality_sums_at``: ℓ1 on
+    the fused Pallas backends). ``pairwise_fn`` overrides the distance
+    block directly (the legacy hook of ``correlated_sequential_halving``;
+    takes precedence over ``backend``, and takes gathered rows).
     """
     from repro.core import distances
     from repro.core.backend import get_backend
 
-    tile = None
+    tile = score_at = None
     if pairwise_fn is not None:
         def plain(x, y):
             return jnp.sum(pairwise_fn(x, y), axis=1)
@@ -163,6 +170,8 @@ def medoid_centrality(backend=None, metric: str = "l2", *,
         masked = _masked_centrality_fn(be, fn, metric)
         tile = be.tile if be.centrality_tile is None \
             else be.centrality_tile(metric)
+        if be.centrality_sums_at is not None:
+            score_at = be.centrality_sums_at(metric)
 
     def score(cand, ref_rows, *, refs, ref_mask=None):
         if ref_mask is None:
@@ -171,7 +180,8 @@ def medoid_centrality(backend=None, metric: str = "l2", *,
 
     # a caller's own pairwise_fn takes whatever norms it takes, unseen
     return ArmEstimator("medoid_centrality", score, tile,
-                        pairwise_fn is None and _takes_norms(metric))
+                        pairwise_fn is None and _takes_norms(metric),
+                        score_at)
 
 
 def build_delta(backend=None, metric: str = "l2", *,

@@ -68,6 +68,9 @@ if TYPE_CHECKING:   # repro.core is imported lazily (see resolve_order_fn)
 BackendLike = Union[str, "DistanceBackend", None]
 SelectFn = Callable[[jnp.ndarray, int], jnp.ndarray]
 OrderFn = Callable[[jnp.ndarray], jnp.ndarray]
+# (score by index vectors, its kernel's block, whether it gathers rows):
+# see _row_scorer
+Scorer = tuple[Callable[..., Any], Any, bool]
 
 # Rounds per scan body (the compile-vs-compute knob; see Schedule.stacked).
 DEFAULT_BAND_ROUNDS = 3
@@ -206,8 +209,37 @@ class HalvingOutcome:
     margin_ok: Any = None
 
 
+def _row_scorer(problem: HalvingProblem) -> Scorer:
+    """How this run's estimator calls reach their rows, decided once per
+    run: ``(score, tile, gathers)``, with ``score(cand_idx, ref_idx, *,
+    refs, ref_mask)`` -> ``(sums, aux)`` and ``tile`` the block its kernel
+    pads a call to. Where the estimator fetches rows by index
+    (``ArmEstimator.score_at``), the corpus is laid out for it here, once,
+    and no call copies rows; otherwise every call scores
+    ``data[cand_idx]`` against ``data[ref_idx]`` (``gathers``)."""
+    data, est = problem.data, problem.estimator
+    n = data.shape[0]
+    at = None if est.score_at is None else est.score_at(data)
+    if at is not None:
+        sums_at, tile = at
+
+        def indexed(cand_idx, ref_idx, *, refs, ref_mask=None):
+            if cand_idx.shape[0] < n:
+                return sums_at(cand_idx, ref_idx, ref_mask=ref_mask), None
+            # a buffer as long as the corpus is a permutation of it (the
+            # first band's): score every row in storage order, then read
+            # the sums in the buffer's
+            return sums_at(None, ref_idx, ref_mask=ref_mask)[cand_idx], None
+        return indexed, tile, False
+
+    def gathered(cand_idx, ref_idx, **kw):
+        return est.score(data[cand_idx], data[ref_idx], **kw)
+    return gathered, est.tile, True
+
+
 def _scan_band(problem: HalvingProblem, band: StackedBand, order_fn: OrderFn,
-               key: jax.Array, buf: jnp.ndarray, telemetry: bool = False):
+               key: jax.Array, buf: jnp.ndarray, scorer: Scorer,
+               telemetry: bool = False):
     """Run one band of halving rounds as a single ``lax.scan``.
 
     ``buf`` is the fixed-width survivor buffer (``band.width`` global arm
@@ -224,6 +256,7 @@ def _scan_band(problem: HalvingProblem, band: StackedBand, order_fn: OrderFn,
     so the carry (and every selection decision) is untouched.
     """
     data, est = problem.data, problem.estimator
+    score, tile, gathers = scorer
     n = data.shape[0]
     width, cap = band.width, band.ref_cap
     xs = (jnp.asarray(band.survivors, jnp.int32),
@@ -244,7 +277,7 @@ def _scan_band(problem: HalvingProblem, band: StackedBand, order_fn: OrderFn,
         else:
             w = pos_ok.astype(jnp.float32)
             denom = t_r.astype(jnp.float32)
-        sums, _ = est.score(data[buf], data[refs], refs=refs, ref_mask=w)
+        sums, _ = score(buf, refs, refs=refs, ref_mask=w)
         theta = sums / denom                              # (width,)
         alive = jnp.arange(width, dtype=jnp.int32) < s_r
         theta = jnp.where(alive, theta, jnp.inf)
@@ -255,7 +288,7 @@ def _scan_band(problem: HalvingProblem, band: StackedBand, order_fn: OrderFn,
         return (key, buf), ys
 
     instrument.note_score(width, cap, data.shape[1], runs=len(band),
-                          tile=est.tile, norms=est.norms)
+                          tile=tile, norms=est.norms, gathers=gathers)
     (key, buf), rows = jax.lax.scan(body, (key, buf), xs)
     return key, buf, rows
 
@@ -263,7 +296,8 @@ def _scan_band(problem: HalvingProblem, band: StackedBand, order_fn: OrderFn,
 def _scan_band_widened(problem: HalvingProblem, band: StackedBand,
                        keeps: Sequence[int], order_fn: OrderFn,
                        key: jax.Array, buf: jnp.ndarray, live: jnp.ndarray,
-                       widen: jnp.ndarray, telemetry: bool = False):
+                       widen: jnp.ndarray, scorer: Scorer,
+                       telemetry: bool = False):
     """One band of *margin-widened* halving rounds as a single ``lax.scan``.
 
     Identical to :func:`_scan_band` (same key sequence, draws, scoring, and
@@ -277,6 +311,7 @@ def _scan_band_widened(problem: HalvingProblem, band: StackedBand,
     static band-boundary slices, which the caller accounts in ``margin_ok``.
     """
     data, est = problem.data, problem.estimator
+    score, tile, gathers = scorer
     n = data.shape[0]
     width, cap = band.width, band.ref_cap
     xs = (jnp.asarray(band.num_refs, jnp.int32),
@@ -297,7 +332,7 @@ def _scan_band_widened(problem: HalvingProblem, band: StackedBand,
         else:
             w = pos_ok.astype(jnp.float32)
             denom = t_r.astype(jnp.float32)
-        sums, _ = est.score(data[buf], data[refs], refs=refs, ref_mask=w)
+        sums, _ = score(buf, refs, refs=refs, ref_mask=w)
         theta = sums / denom                              # (width,)
         alive = jnp.arange(width, dtype=jnp.int32) < live
         theta = jnp.where(alive, theta, jnp.inf)
@@ -314,7 +349,7 @@ def _scan_band_widened(problem: HalvingProblem, band: StackedBand,
         return (key, buf, live), ys
 
     instrument.note_score(width, cap, data.shape[1], runs=len(band),
-                          tile=est.tile, norms=est.norms)
+                          tile=tile, norms=est.norms, gathers=gathers)
     (key, buf, live), rows = jax.lax.scan(body, (key, buf, live), xs)
     return key, buf, live, rows
 
@@ -327,6 +362,7 @@ def _run_halving_widened(problem: HalvingProblem, sched, order_fn: OrderFn,
     data, est = problem.data, problem.estimator
     n = data.shape[0]
     stk = sched.stacked(n, band_rounds=band_rounds, slack=WIDEN_SLACK)
+    scorer = _row_scorer(problem)
     widen = jnp.asarray(widen, jnp.float32)
     idx = jnp.arange(n, dtype=jnp.int32)
     live = jnp.asarray(n, jnp.int32)
@@ -341,7 +377,7 @@ def _run_halving_widened(problem: HalvingProblem, sched, order_fn: OrderFn,
         idx = idx[:band.width]
         keeps = tuple(stk.sizes[band.start + i + 1] for i in range(len(band)))
         key, idx, live, rows = _scan_band_widened(
-            problem, band, keeps, order_fn, key, idx, live, widen,
+            problem, band, keeps, order_fn, key, idx, live, widen, scorer,
             telemetry=telemetry)
         if telemetry:
             scanned_rows.append(rows)
@@ -360,10 +396,10 @@ def _run_halving_widened(problem: HalvingProblem, sched, order_fn: OrderFn,
         refs = sample_refs(sub, n, rd.num_refs)
         ref_mask = None
         denom = refs.shape[0]              # static Python int
+    score, tile, gathers = scorer
     instrument.note_score(survivors.shape[0], refs.shape[0], data.shape[1],
-                          tile=est.tile, norms=est.norms)
-    sums, aux = est.score(data[survivors], data[refs], refs=refs,
-                          ref_mask=ref_mask)
+                          tile=tile, norms=est.norms, gathers=gathers)
+    sums, aux = score(survivors, refs, refs=refs, ref_mask=ref_mask)
     theta = sums / denom
     theta = jnp.where(jnp.arange(out_cap, dtype=jnp.int32) < live,
                       theta, jnp.inf)
@@ -436,12 +472,13 @@ def run_halving(problem: HalvingProblem, schedule: Sequence[Round],
     data, est = problem.data, problem.estimator
     n = data.shape[0]
     stk = sched.stacked(n, band_rounds=band_rounds)
+    scorer = _row_scorer(problem)
     idx = jnp.arange(n, dtype=jnp.int32)
     scanned_rows = []
     for band in stk.bands:
         idx = idx[:band.width]            # static slice: sorted live prefix
         key, idx, rows = _scan_band(problem, band, order_fn, key, idx,
-                                    telemetry=telemetry)
+                                    scorer, telemetry=telemetry)
         if telemetry:
             scanned_rows.append(rows)
 
@@ -459,10 +496,10 @@ def run_halving(problem: HalvingProblem, schedule: Sequence[Round],
         refs = sample_refs(sub, n, rd.num_refs)
         ref_mask = None
         denom = refs.shape[0]              # static Python int
+    score, tile, gathers = scorer
     instrument.note_score(survivors.shape[0], refs.shape[0], data.shape[1],
-                          tile=est.tile, norms=est.norms)
-    sums, aux = est.score(data[survivors], data[refs], refs=refs,
-                          ref_mask=ref_mask)
+                          tile=tile, norms=est.norms, gathers=gathers)
+    sums, aux = score(survivors, refs, refs=refs, ref_mask=ref_mask)
     theta = sums / denom
     if problem.arm_mask is not None:
         theta = jnp.where(problem.arm_mask[survivors], theta, jnp.inf)

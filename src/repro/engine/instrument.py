@@ -30,6 +30,8 @@ block padded to the kernel's tile — what the kernel actually evaluates
 kernel does, is given as that rule). ``normed`` counts the operand rows
 whose norms a Gram metric takes before the kernel on every call (cosine's
 unit rows, ℓ2's squared norms; none for ℓ1): ``rows + refs`` per call.
+``gathered`` counts the rows the round loop copies out of the corpus for
+a call (``rows + refs``), where the estimator takes rows and not indices.
 No device work, no host sync.
 """
 from __future__ import annotations
@@ -49,7 +51,8 @@ _DISPATCHES: Counter = Counter()
 _CALLED: Counter = Counter()
 _COMPUTED: Counter = Counter()
 _NORMED: Counter = Counter()
-_WORK = (_CALLED, _COMPUTED, _NORMED)   # the fields of Work, in order
+_GATHERED: Counter = Counter()
+_WORK = (_CALLED, _COMPUTED, _NORMED, _GATHERED)  # Work's fields, in order
 _TALLIES: list = []          # open trace-time (Work, copies) collectors
 
 
@@ -57,10 +60,12 @@ _TALLIES: list = []          # open trace-time (Work, copies) collectors
 class Work:
     """Distance terms of one dispatch: asked for (``called``) and evaluated
     after tile padding (``computed``); operand rows whose norms the calls
-    take (``normed``)."""
+    take (``normed``); rows copied out of the corpus for them
+    (``gathered``)."""
     called: int = 0
     computed: int = 0
     normed: int = 0
+    gathered: int = 0
 
 
 def _padded(size: int, block: int) -> int:
@@ -68,14 +73,16 @@ def _padded(size: int, block: int) -> int:
 
 
 def note_score(rows: int, refs: int, width: int, *, runs: int = 1,
-               tile: Optional[Tile] = None, norms: bool = False) -> None:
+               tile: Optional[Tile] = None, norms: bool = False,
+               gathers: bool = False) -> None:
     """Record one estimator call over a ``(rows, width) x (refs, width)``
     block that runs ``runs`` times per program run (call at trace time,
     outside any scan body: a scan body is traced once but runs once per
     scanned round). ``tile`` = (row, reference, width) block of the kernel
     that evaluates it, or the rule that gives that block for the call's
     ``(rows, refs, width)``; ``None`` = no padding. ``norms``: the call
-    takes the norm of every operand row first. Does nothing outside a
+    takes the norm of every operand row first. ``gathers``: its rows are
+    copied out of the corpus for it. Does nothing outside a
     :func:`tally`."""
     if not _TALLIES:
         return
@@ -90,6 +97,8 @@ def note_score(rows: int, refs: int, width: int, *, runs: int = 1,
     work.computed += copies * runs * computed
     if norms:
         work.normed += copies * runs * (rows + refs)
+    if gathers:
+        work.gathered += copies * runs * (rows + refs)
 
 
 @contextlib.contextmanager
@@ -111,14 +120,16 @@ def note_work(kind: str, work: Work) -> None:
     _CALLED[kind] += work.called
     _COMPUTED[kind] += work.computed
     _NORMED[kind] += work.normed
+    _GATHERED[kind] += work.gathered
 
 
 def work_counters() -> dict:
-    """Snapshot of the work odometer (per kind: distance terms, and rows
-    normed)."""
+    """Snapshot of the work odometer (per kind: distance terms, rows
+    normed and rows gathered)."""
     return {"called": dict(sorted(_CALLED.items())),
             "computed": dict(sorted(_COMPUTED.items())),
-            "normed": dict(sorted(_NORMED.items()))}
+            "normed": dict(sorted(_NORMED.items())),
+            "gathered": dict(sorted(_GATHERED.items()))}
 
 
 def note_trace(kind: str) -> None:

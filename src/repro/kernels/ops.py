@@ -20,6 +20,8 @@ from repro.kernels import pairwise_distance as pk
 # the fused ℓ1 centrality, whose block follows the call's shape
 # (``centrality_tile``): the shape of the work a kernel call evaluates.
 TILE = (pk.BC, pk.BR, pk.BD)
+# The indexed ℓ1 kernel's block rule: candidate tiles of whole rows.
+L1_TILE_AT = functools.partial(pk.l1_centrality_tile, indexed=True)
 
 
 def interpret_mode() -> bool:
@@ -112,6 +114,158 @@ def kernel_l1_centrality(x: jnp.ndarray, y: jnp.ndarray,
     if ref_mask is None:
         return sums / y.shape[0]
     return sums / jnp.maximum(jnp.sum(ref_mask.astype(jnp.float32)), 1.0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def l1_rows(data: jnp.ndarray, interpret: bool | None = None) -> jnp.ndarray:
+    """The chunk view of an ``(n, d)`` corpus that the indexed ℓ1 kernel
+    fetches rows from: ``(n * pk.l1_row_chunks(d), 128)`` f32, row i in
+    rows ``[i * nch, (i + 1) * nch)``, zero past ``d``. One copy of the
+    corpus, made once per run of the round loop."""
+    interp = interpret_mode() if interpret is None else interpret
+    return pk.l1_rows_view(data.astype(jnp.float32), interpret=interp)
+
+
+def _within_smem(call, ci: jnp.ndarray, ri: jnp.ndarray, w: jnp.ndarray,
+                 bc: int) -> jnp.ndarray:
+    """``call(ci, ri, w)`` over (B, Cp) candidate and (B, Rp) reference
+    index vectors in as few kernel calls as keep each call's vectors within
+    ``pk.L1_INDEX_BYTES`` of SMEM: whole queries where one fits, else one
+    query's candidate tiles in pieces. Every piece reads all its query's
+    references, so each sum is the one a single call gives."""
+    b, cp = ci.shape
+    rp = ri.shape[1]
+    query = 4 * (cp + 2 * rp)
+    if b * query <= pk.L1_INDEX_BYTES:
+        return call(ci, ri, w)
+    if query <= pk.L1_INDEX_BYTES:
+        q = pk.L1_INDEX_BYTES // query
+        return jnp.concatenate([call(ci[i:i + q], ri[i:i + q], w[i:i + q])
+                                for i in range(0, b, q)])
+    piece = (pk.L1_INDEX_BYTES // 4 - 2 * rp) // bc * bc
+    if piece <= 0:
+        raise ValueError(f"{rp} references do not fit the indexed ℓ1 "
+                         f"kernel's SMEM; gather the rows instead")
+    return jnp.stack([
+        jnp.concatenate([call(ci[i:i + 1, j:j + piece], ri[i:i + 1],
+                              w[i:i + 1])[0] for j in range(0, cp, piece)])
+        for i in range(b)])
+
+
+def l1_index_fits(n: int) -> bool:
+    """Whether one query's reference indices and weights over an ``n``-row
+    corpus fit the indexed kernel's SMEM, beside a candidate tile."""
+    return 4 * (2 * (n + pk.L1_REFS) + pk.L1_ROWS) <= pk.L1_INDEX_BYTES
+
+
+@functools.cache
+def _l1_sums_at(d: int, block: tuple[int, int, int], interpret: bool):
+    """``pk.l1_centrality_at`` at one static width and block, batched by
+    stacking: a vmapped call becomes one kernel call whose queries read
+    one stacked corpus view, their indices offset to their own rows."""
+    nch = pk.l1_row_chunks(d)
+
+    @jax.custom_batching.custom_vmap
+    def sums(rows, ci, ri, w):
+        return _within_smem(functools.partial(
+            pk.l1_centrality_at, rows, d=d, block=block,
+            interpret=interpret), ci, ri, w, block[0])
+
+    @sums.def_vmap
+    def _stacked(size, batched, rows, ci, ri, w):
+        rows_b, ci_b, ri_b, w_b = batched
+        offset = 0
+        if rows_b:
+            offset = (jnp.arange(size, dtype=jnp.int32)
+                      * (rows.shape[1] // nch))[:, None, None]
+            rows = rows.reshape(-1, pk.LANES)
+
+        def stack(a, a_b):
+            a = a if a_b else jnp.broadcast_to(a, (size,) + a.shape)
+            return a.reshape(-1, a.shape[-1])
+
+        out = sums(rows, stack(ci + offset, ci_b or rows_b),
+                   stack(ri + offset, ri_b or rows_b), stack(w, w_b))
+        return out.reshape(size, -1, out.shape[-1]), True
+
+    return sums
+
+
+@functools.cache
+def _l1_sums_all(d: int, block: tuple[int, int, int], interpret: bool):
+    """``pk.l1_centrality_at`` with every corpus row a candidate, in
+    storage order. Vmapped, it becomes the indexed call over each query's
+    rows in order, stacked."""
+    @jax.custom_batching.custom_vmap
+    def sums(rows, corpus, ri, w):
+        return pk.l1_centrality_at(rows, None, ri, w, d=d, block=block,
+                                   corpus=corpus, interpret=interpret)
+
+    @sums.def_vmap
+    def _indexed(size, batched, rows, corpus, ri, w):
+        m = corpus.shape[-2]
+        cp = m + (-m) % block[0]
+        ci = jnp.where(jnp.arange(cp) < m, jnp.arange(cp), 0)[None]
+        axes = [0 if b else None for b in (batched[0], batched[2],
+                                            batched[3])]
+        out = jax.vmap(_l1_sums_at(d, block, interpret),
+                       in_axes=(axes[0], None, axes[1], axes[2]),
+                       axis_size=size)(rows, ci, ri, w)
+        return out, True
+
+    return sums
+
+
+@functools.partial(jax.jit, static_argnames=("width", "interpret"))
+def kernel_centrality_sums_at(rows: jnp.ndarray,
+                              cand_idx: jnp.ndarray | None,
+                              ref_idx: jnp.ndarray, *, width: int,
+                              interpret: bool | None = None,
+                              ref_mask: jnp.ndarray | None = None,
+                              corpus: jnp.ndarray | None = None
+                              ) -> jnp.ndarray:
+    """ℓ1 ``kernel_centrality_sums(data[cand_idx], data[ref_idx])``
+    without the gathers: the kernel fetches the rows from ``rows =
+    l1_rows(data)`` itself (``width`` is ``data``'s). Index vectors pad
+    with row 0 to the block ``L1_TILE_AT`` gives the call, the padded
+    references weigh 0, and the sums are bitwise those of the gathered
+    call at that block. ``cand_idx=None`` with ``corpus=data``: every row
+    is a candidate, in storage order, read from ``data`` as it lies."""
+    interp = interpret_mode() if interpret is None else interpret
+    c = corpus.shape[0] if cand_idx is None else cand_idx.shape[0]
+    r = ref_idx.shape[0]
+    bc, br, _ = block = L1_TILE_AT(c, r, width)
+    ri = jnp.pad(ref_idx.astype(jnp.int32), (0, (-r) % br))
+    w = (jnp.arange(ri.shape[0]) < r).astype(jnp.float32)
+    if ref_mask is not None:
+        w = w * _pad_ref_mask(ref_mask, r, ri.shape[0])
+    if cand_idx is None:
+        return _l1_sums_all(width, block, interp)(
+            rows, corpus.astype(jnp.float32), ri[None], w[None])[0, :c]
+    ci = jnp.pad(cand_idx.astype(jnp.int32), (0, (-c) % bc))
+    return _l1_sums_at(width, block, interp)(
+        rows, ci[None], ri[None], w[None])[0, :c]
+
+
+def _l1_centrality_on(data: jnp.ndarray):
+    """Lay ``data`` out once for the indexed ℓ1 kernel and return
+    ``((cand_idx, ref_idx, ref_mask=None) -> (C,) sums, block rule)`` over
+    it (``cand_idx=None``: every row, in storage order), or ``None`` where
+    its rows are too wide for the kernel's VMEM or its reference draws
+    too long for its SMEM."""
+    n, width = data.shape
+    if not (pk.l1_rows_cap(width) and l1_index_fits(n)):
+        return None
+    return functools.partial(kernel_centrality_sums_at, l1_rows(data),
+                             width=width, corpus=data), L1_TILE_AT
+
+
+def centrality_kernel_at(metric: str):
+    """The fused centrality kernel that fetches its rows by index, for
+    ``metric``: ``data -> None or ((cand_idx, ref_idx, ref_mask=None) ->
+    (C,) sums, its block rule)``. ℓ1 only; ``None`` for the Gram
+    metrics."""
+    return _l1_centrality_on if metric == "l1" else None
 
 
 def _norms_sq(a: jnp.ndarray) -> jnp.ndarray:

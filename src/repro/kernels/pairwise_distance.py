@@ -9,7 +9,7 @@ metrics into two kernel families:
   computed outside the kernel (Gram trick), so the inner loop runs on the
   128x128 systolic array at full rate.
 
-* **ℓ1 kernels** (VPU path): ``sum |x - y|`` has no matmul form. Two
+* **ℓ1 kernels** (VPU path): ``sum |x - y|`` has no matmul form. Three
   variants:
     - ``l1_pairwise``  -> (C, R) distance matrix. It tiles
       ``(BC, BD) x (BD, BR)`` (the reference block transposed) into VMEM and
@@ -18,6 +18,9 @@ metrics into two kernel families:
       HBM. Both operands keep d on lanes, and its tile is sized from each
       call's shape (``l1_centrality_tile``), since the round loop makes one
       of its two row axes small in every call.
+    - ``l1_centrality_at``-> the same sums with the rows named by index:
+      the kernel copies them out of the resident corpus itself, so the
+      round loop neither gathers nor pads them.
 
 Grid layout: (i, j, k) with k (the d-axis) innermost so each output tile is
 revisited across k steps and accumulated in place (standard Pallas reduction
@@ -176,6 +179,12 @@ L1_ROWS = 128            # candidate rows per tile: a (128, 128) f32 partial
                          # is 16 vregs, and the step's sum 16 more
 L1_REFS = 512            # reference rows per tile
 L1_TILE_BYTES = 8 << 20  # both input tiles, double-buffered, f32
+L1_ROW_BYTES = 32 << 20  # whole candidate rows, twice, and the ring of
+                         # reference groups, f32 (the indexed kernel,
+                         # ``l1_centrality_at``)
+L1_REF_SLOTS = 4         # its reference groups of 8 rows in VMEM: one in
+                         # use, the next three in flight
+L1_INDEX_BYTES = 768 << 10  # its index and weight vectors, in SMEM (1 MiB)
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -188,7 +197,27 @@ def _even_block(n: int, cap: int) -> int:
     return _cdiv(_cdiv(n, _cdiv(n, cap)), SUBLANES) * SUBLANES
 
 
-def l1_centrality_tile(c: int, r: int, d: int) -> tuple[int, int, int]:
+def l1_row_chunks(d: int) -> int:
+    """128-lane chunks of one row of the corpus view the indexed kernel
+    fetches from (``l1_rows``): every width block ``l1_centrality_tile``
+    can give a ``d``-wide call, rounded up to the sublane multiple so each
+    row is whole (8, 128) tiles."""
+    widest = max(d + d // 100, (_cdiv(d, LANES) + 1) * LANES)
+    return _cdiv(_cdiv(widest, LANES), SUBLANES) * SUBLANES
+
+
+def l1_rows_cap(d: int) -> int:
+    """The most candidate rows of width ``d`` that the indexed kernel can
+    hold whole in ``L1_ROW_BYTES``, twice (as they land, and chunk-major),
+    beside its ring of reference groups; 0 where not even 8 fit (the
+    indexed kernel then cannot take the width)."""
+    rows = L1_ROW_BYTES // (4 * LANES * l1_row_chunks(d))
+    most = (rows - L1_REF_SLOTS * SUBLANES) // 2
+    return max(0, most // SUBLANES * SUBLANES)
+
+
+def l1_centrality_tile(c: int, r: int, d: int, *,
+                       indexed: bool = False) -> tuple[int, int, int]:
     """(candidate, reference, width) block of the fused ℓ1 kernel for a
     ``(c, d) x (r, d)`` call: a pure function of the static shape.
 
@@ -197,8 +226,13 @@ def l1_centrality_tile(c: int, r: int, d: int) -> tuple[int, int, int]:
     the widest multiple of 128 whose double-buffered tiles fit
     ``L1_TILE_BYTES`` and that pads ``d`` by at most 1% (or one 128-lane
     chunk past its lane rounding, where 1% is less than that).
+
+    ``indexed``: the block of the indexed kernel (``l1_centrality_at``),
+    whose candidate tiles hold whole rows, so at most ``l1_rows_cap(d)``
+    of them (the same block up to about 32,000 wide).
     """
-    bc = _even_block(max(c, 1), L1_ROWS)
+    rows = min(L1_ROWS, l1_rows_cap(d)) if indexed else L1_ROWS
+    bc = _even_block(max(c, 1), max(SUBLANES, rows))
     br = _even_block(max(r, 1), L1_REFS)
     chunks = _cdiv(max(d, 1), LANES)
     most = max(1, min(chunks, L1_TILE_BYTES // (2 * 4 * (bc + br) * LANES)))
@@ -274,6 +308,302 @@ def l1_centrality(x: jnp.ndarray, y: jnp.ndarray, r_true: int, *,
                         pltpu.VMEM((br, LANES), jnp.float32)],
         interpret=interpret,
     )(x, y, mask.reshape(r, 1))
+
+
+# --------------------------------------------------------------------------
+# indexed ℓ1 centrality kernel: the fused ℓ1 kernel's sums for candidate and
+# reference rows named by index, fetched from the resident corpus by the
+# kernel's own DMAs, so no (C, d) or (R, d) copy of them exists in HBM.
+#
+# Mosaic copies only whole (8, 128) tiles of a tiled HBM array, and a row of
+# the (n, d) corpus is one sublane of its tiles. So the kernel reads a chunk
+# view of the corpus (``l1_rows_view``: row i is rows [i*nch, (i+1)*nch) of
+# an (n*nch, 128) array, zero past d), one DMA per row. Candidate tiles
+# come either by index (each lands whole in VMEM and is copied chunk-major
+# once) or, where every row is a candidate, straight from the corpus in
+# storage order. The sums are bitwise those of ``l1_centrality`` on the
+# gathered rows at the same block (see ``_l1_sums_by_index``).
+#
+# One grid step per (query, candidate tile): ``ci`` holds each query's
+# padded candidates in turn, ``ri`` and ``w`` its padded references and
+# their weights, all as global row indices (a batch of queries reads one
+# stacked corpus view).
+# --------------------------------------------------------------------------
+
+L1_VIEW_ROWS = 16       # corpus rows the chunk-view copy moves per step
+
+
+def _l1_rows_kernel(x_ref, o_ref, *, d: int, nch: int):
+    rows = x_ref.shape[0]
+    for k in range(nch):                   # chunk k of each row, strided
+        if k * LANES < d:
+            v = x_ref[:, k * LANES:(k + 1) * LANES]
+            if (k + 1) * LANES > d:        # the lanes past d are not data
+                lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+                v = jnp.where(lane < d - k * LANES, v, 0.0)
+        else:
+            v = jnp.zeros((rows, LANES), jnp.float32)
+        o_ref[pl.ds(k, rows, stride=nch), :] = v
+
+
+def l1_rows_view(x: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
+    """The chunk view of an (n, d) f32 corpus: (n * nch, 128), row i in
+    rows [i * nch, (i + 1) * nch), zero past d (nch = ``l1_row_chunks``).
+    One pass over the corpus (XLA's pad and reshape make two)."""
+    n, d = x.shape
+    nch = l1_row_chunks(d)
+    width = _cdiv(d, LANES) * LANES
+    return pl.pallas_call(
+        functools.partial(_l1_rows_kernel, d=d, nch=nch),
+        grid=(_cdiv(n, L1_VIEW_ROWS),),
+        in_specs=[pl.BlockSpec((L1_VIEW_ROWS, width), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((L1_VIEW_ROWS * nch, LANES),
+                               lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n * nch, LANES), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=4 * L1_VIEW_ROWS * 4 * nch * LANES + (4 << 20)),
+        interpret=interpret,
+    )(x)
+
+
+def _l1_sums_by_index(x_block, ri_ref, w_ref, rows_hbm, o_ref, ybuf,
+                      step_ref, acc_ref, ysem, *, nch: int, bd: int, nk: int,
+                      br: int, rp: int, tiles: int, on_block=None):
+    """One grid step of the indexed ℓ1 kernels: the step's candidate tile
+    (``x_block(c0)(off)`` gives its (bc, 128) block of chunk c0 + off)
+    against its query's references, streamed from the corpus chunk view
+    in groups of 8 through a ring of ``L1_REF_SLOTS`` buffers (each
+    reference's chunk is one row of its group's buffer). ``on_block(gg,
+    k)`` runs before width block k of reference group gg. The additions
+    run in the gathered kernel's order: per reference tile, one (bc, 128)
+    sum per width block over the tile's references, folded into the
+    accumulator in width-block order."""
+    s = pl.program_id(0)
+    steps = pl.num_programs(0)
+    bc = o_ref.shape[0]
+    groups = rp // SUBLANES                # reference groups of a query
+    slots = ysem.shape[0]
+
+    def fetch_group(q):
+        # group q of the reference stream (group q % groups of grid step
+        # q // groups), into ring slot q % slots
+        first = (q // groups // tiles) * rp + (q % groups) * SUBLANES
+
+        def one(rr, carry):
+            _row_copy(rows_hbm, nch, ri_ref[first + rr], ybuf,
+                      (q % slots) * SUBLANES + rr, ysem.at[q % slots]).start()
+            return carry
+        jax.lax.fori_loop(0, SUBLANES, one, 0)
+
+    @pl.when(s == 0)
+    def _prime():
+        def one(q, carry):
+            @pl.when(q < steps * groups)
+            def _():
+                fetch_group(q)
+            return carry
+        jax.lax.fori_loop(0, slots - 1, one, 0)
+
+    wbase = (s // tiles) * rp
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def ref_tile(j, carry):
+        step_ref[...] = jnp.zeros_like(step_ref)
+
+        def group(g, carry):
+            gg = j * (br // SUBLANES) + g
+            q = s * groups + gg
+            _wait_rows(rows_hbm, nch, ybuf, ysem.at[q % slots], SUBLANES)
+
+            @pl.when(q + slots - 1 < steps * groups)
+            def _ahead():
+                fetch_group(q + slots - 1)
+
+            ybase = (q % slots) * SUBLANES * nch
+            w0 = wbase + gg * SUBLANES
+
+            def width_block(k, carry):
+                if on_block is not None:
+                    on_block(gg, k)
+                c0 = k * (bd // LANES)
+                x = x_block(c0)              # this block's chunks, as views
+                y = ybuf.at[pl.ds(ybase + c0, SUBLANES * nch)]
+                step = step_ref[k]
+                for rr in range(SUBLANES):
+                    part = jnp.zeros((bc, LANES), jnp.float32)
+                    for off in range(bd // LANES):  # static, as there
+                        yr = y[rr * nch + off:rr * nch + off + 1, :]
+                        part = part + jnp.abs(x(off) - yr)
+                    step = step + part * w_ref[w0 + rr]
+                step_ref[k] = step
+                return carry
+
+            jax.lax.fori_loop(0, nk, width_block, 0)
+            return carry
+
+        jax.lax.fori_loop(0, br // SUBLANES, group, 0)
+        for k in range(nk):
+            acc_ref[...] += step_ref[k]
+        return carry
+
+    jax.lax.fori_loop(0, rp // br, ref_tile, 0)
+    o_ref[...] = jnp.sum(acc_ref[...], axis=1, keepdims=True)
+
+
+def _row_copy(rows_hbm, nch, row, buf, at, sem):
+    """DMA of corpus row ``row`` (its ``nch`` rows of the chunk view) to
+    rows ``[at * nch, (at + 1) * nch)`` of ``buf``."""
+    return pltpu.make_async_copy(
+        rows_hbm.at[pl.ds(pl.multiple_of(row * nch, SUBLANES), nch)],
+        buf.at[pl.ds(pl.multiple_of(at * nch, SUBLANES), nch)], sem)
+
+
+def _wait_rows(rows_hbm, nch, buf, sem, count):
+    """Wait for ``count`` row copies on ``sem``."""
+    def one(c, carry):
+        _row_copy(rows_hbm, nch, 0, buf, 0, sem).wait()
+        return carry
+    jax.lax.fori_loop(0, count, one, 0)
+
+
+def _l1_centrality_at_kernel(ci_ref, ri_ref, w_ref, rows_hbm, o_ref, xbuf,
+                             xt_ref, ybuf, step_ref, acc_ref, xsem, ysem,
+                             **dims):
+    # candidate rows named by ``ci``: a tile lands whole in VMEM, is copied
+    # chunk-major once (a strided load of stride nch gathers chunk q of its
+    # rows), and the next tile's rows are started a share per width block
+    # of this step's work, not in one burst: a burst stalls on the DMA
+    # queue, in series with the work instead of under it
+    s = pl.program_id(0)
+    steps = pl.num_programs(0)
+    bc = o_ref.shape[0]
+    nch, nk = dims["nch"], dims["nk"]
+    per_block = _cdiv(bc, dims["rp"] // SUBLANES * nk)
+
+    def fetch_candidate(step, c):
+        _row_copy(rows_hbm, nch, ci_ref[step * bc + c], xbuf, c,
+                  xsem.at[0]).start()
+
+    @pl.when(s == 0)
+    def _prime():
+        def start(c, carry):
+            fetch_candidate(0, c)
+            return carry
+        jax.lax.fori_loop(0, bc, start, 0)
+
+    _wait_rows(rows_hbm, nch, xbuf, xsem.at[0], bc)
+
+    def chunk_major(q, carry):
+        xt_ref[pl.ds(pl.multiple_of(q * bc, SUBLANES), bc), :] = \
+            xbuf[pl.ds(q, bc, stride=nch), :]
+        return carry
+    jax.lax.fori_loop(0, xt_ref.shape[0] // bc, chunk_major, 0)
+
+    def next_tile(gg, k):
+        @pl.when(s + 1 < steps)
+        def _():
+            for i in range(per_block):
+                c = (gg * nk + k) * per_block + i
+
+                @pl.when(c < bc)
+                def _():
+                    fetch_candidate(s + 1, c)
+
+    def x_block(c0):
+        xs = xt_ref.at[pl.ds(pl.multiple_of(c0 * bc, SUBLANES),
+                             dims["bd"] // LANES * bc)]
+        return lambda off: xs[off * bc:(off + 1) * bc, :]
+
+    _l1_sums_by_index(x_block, ri_ref, w_ref, rows_hbm, o_ref, ybuf,
+                      step_ref, acc_ref, ysem, on_block=next_tile, **dims)
+
+
+def _l1_centrality_all_kernel(ri_ref, w_ref, x_ref, rows_hbm, o_ref, ybuf,
+                              step_ref, acc_ref, ysem, *, d: int, **dims):
+    # every corpus row a candidate, in storage order: the tile streams
+    # straight from the (n, d) corpus, candidates already on sublanes; the
+    # lanes past d are not data
+    lo = d // LANES * LANES
+    if lo < x_ref.shape[1]:
+        lane = jax.lax.broadcasted_iota(
+            jnp.int32, (x_ref.shape[0], x_ref.shape[1] - lo), 1)
+        x_ref[:, lo:] = jnp.where(lane < d - lo, x_ref[:, lo:], 0.0)
+
+    def x_block(c0):
+        xs = x_ref.at[:, pl.ds(pl.multiple_of(c0 * LANES, LANES),
+                               dims["bd"])]
+        return lambda off: xs[:, off * LANES:(off + 1) * LANES]
+
+    _l1_sums_by_index(x_block, ri_ref, w_ref, rows_hbm, o_ref, ybuf,
+                      step_ref, acc_ref, ysem, **dims)
+
+
+def l1_centrality_at(rows: jnp.ndarray, ci: jnp.ndarray | None,
+                     ri: jnp.ndarray, w: jnp.ndarray, *, d: int,
+                     block: tuple[int, int, int],
+                     corpus: jnp.ndarray | None = None,
+                     interpret: bool = False) -> jnp.ndarray:
+    """Row sums of |X - Y| over weighted references, X and Y named by index.
+
+    rows: the ``(m * l1_row_chunks(d), 128)`` chunk view of ``m`` corpus
+    rows of width ``d`` (``l1_rows_view``); ci: (B, Cp) int32 candidate
+    rows, ri: (B, Rp) int32 reference rows and w: (B, Rp) f32 their weights
+    (0 = invalid), for B queries, Cp and Rp padded to multiples of
+    ``block`` (``l1_centrality_tile(..., indexed=True)`` of the unpadded
+    call) with any valid row. Returns (B, Cp) f32 sums (not yet divided).
+
+    ``ci=None`` with ``corpus``, the ``(m, d)`` corpus itself (B = 1):
+    every row is a candidate, in storage order, streamed from ``corpus``
+    with no copy; returns (1, Cp) for Cp = m padded to the block.
+    """
+    nch = l1_row_chunks(d)
+    bc, br, bd = block
+    nb, rp = ri.shape
+    cp = corpus.shape[0] + (-corpus.shape[0]) % bc if ci is None \
+        else ci.shape[1]
+    nk = _cdiv(d, bd)
+    dims = dict(nch=nch, bd=bd, nk=nk, br=br, rp=rp, tiles=cp // bc)
+    # both kernels: the reference ring, per-width-block sums, accumulator
+    shared = [pltpu.VMEM((L1_REF_SLOTS * SUBLANES * nch, LANES), jnp.float32),
+              pltpu.VMEM((nk, bc, LANES), jnp.float32),
+              pltpu.VMEM((bc, LANES), jnp.float32)]
+    scratch = 4 * LANES * (L1_REF_SLOTS * SUBLANES * nch + (nk + 1) * bc)
+    if ci is None:
+        kern = functools.partial(_l1_centrality_all_kernel, d=d, **dims)
+        prefetch = (ri.reshape(-1), w.reshape(-1))
+        in_specs = [pl.BlockSpec((bc, nk * bd), lambda s, *_: (s, 0)),
+                    pl.BlockSpec(memory_space=pl.ANY)]
+        scratch_shapes = shared + [pltpu.SemaphoreType.DMA((L1_REF_SLOTS,))]
+        scratch += 2 * 4 * bc * nk * bd
+        operands = (corpus, rows)
+    else:
+        kern = functools.partial(_l1_centrality_at_kernel, **dims)
+        prefetch = (ci.reshape(-1), ri.reshape(-1), w.reshape(-1))
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+        scratch_shapes = [
+            pltpu.VMEM((bc * nch, LANES), jnp.float32),
+            pltpu.VMEM((bc * nk * bd // LANES, LANES), jnp.float32),
+            *shared,
+            pltpu.SemaphoreType.DMA((1,)),
+            pltpu.SemaphoreType.DMA((L1_REF_SLOTS,))]
+        scratch += 4 * LANES * (bc * nch + bc * nk * bd // LANES)
+        operands = (rows,)
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(nb * cp // bc,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((bc, 1), lambda s, *_: (s, 0)),
+            scratch_shapes=scratch_shapes),
+        out_shape=jax.ShapeDtypeStruct((nb * cp, 1), jnp.float32),
+        # the grid runs in order: each step waits for copies the previous
+        # one started
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=scratch + (8 << 20)),
+        interpret=interpret,
+    )(*prefetch, *operands).reshape(nb, cp)
 
 
 # --------------------------------------------------------------------------

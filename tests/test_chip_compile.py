@@ -2,12 +2,13 @@
 
 Interpret mode runs the Pallas kernels through XLA:CPU and cannot see what
 the TPU compiler refuses: 1-D vector layouts, lanes padded past the scoped
-VMEM limit, unaligned slices. These tests compile the kernels at
-1024x512x1024 tiles, the fused l1 kernel at both extremes of its
-shape-sized tile at RNA-Seq width, and the l1 ``pallas_fused`` medoid
-program at 4096x512 for a described ``v5e:2x2`` topology, and check that
-the compiled program holds the Mosaic kernel (``tpu_custom_call``) rather
-than an interpreted one.
+VMEM limit, unaligned slices, one-row copies out of a tiled array. These
+tests compile the kernels at 1024x512x1024 tiles, the fused l1 kernel at
+both extremes of its shape-sized tile at RNA-Seq width, the indexed l1
+kernel and its corpus view at RNA-Seq size, and the l1 ``pallas_fused``
+medoid, batch and ragged programs for a described ``v5e:2x2`` topology,
+and check that the compiled program holds the Mosaic kernel
+(``tpu_custom_call``) rather than an interpreted one.
 
 The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and every test worker imports
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.engine import programs
 from repro.kernels import ops
+from repro.kernels import pairwise_distance as pk
 
 C, D, R = 1024, 512, 1024
 
@@ -95,6 +97,40 @@ def test_l1_centrality_compiles_at_both_extreme_tilings(compiled_kernels,
         a, b, metric="l1", ref_mask=m), x, y, mask)
 
 
+@pytest.mark.parametrize("c,r", [(None, 8), (20000, 8), (5, 13333)])
+def test_l1_centrality_at_compiles_at_rnaseq_bands(compiled_kernels,
+                                                    one_chip, c, r):
+    """The indexed kernel at the RNA-Seq 20k schedule's first band (every
+    row in storage order, or 20,000 candidates by index, against 8
+    references) and last (5 against 13,333), reading the chunk view of
+    the 20,000 x 27,998 corpus."""
+    n, d = 20000, 27998
+    rows = _spec((n * pk.l1_row_chunks(d), 128), jnp.float32, one_chip)
+    data = _spec((n, d), jnp.float32, one_chip)
+    ci = None if c is None else _spec((c,), jnp.int32, one_chip)
+    ri = _spec((r,), jnp.int32, one_chip)
+    mask = _spec((r,), jnp.float32, one_chip)
+    compiled = _compile(lambda v, x, a, b, m: ops.kernel_centrality_sums_at(
+        v, a, b, width=d, ref_mask=m, corpus=x), rows, data, ci, ri, mask)
+    assert "kernel_centrality_sums_at" in compiled.as_text()
+
+
+def test_l1_centrality_at_compiles_past_one_calls_smem(compiled_kernels,
+                                                       one_chip):
+    """90,000 candidates against 90,000 references: more indices than
+    one kernel call's SMEM holds, so the candidates go in pieces."""
+    n, d = 90000, 256
+    rows = _spec((n * pk.l1_row_chunks(d), 128), jnp.float32, one_chip)
+    idx = _spec((n,), jnp.int32, one_chip)
+    _compile(lambda v, a, b: ops.kernel_centrality_sums_at(v, a, b, width=d),
+             rows, idx, idx)
+
+
+def test_l1_rows_compiles_at_rnaseq_size(compiled_kernels, one_chip):
+    data = _spec((20000, 27998), jnp.float32, one_chip)
+    _compile(ops.l1_rows, data)
+
+
 @pytest.mark.parametrize("metric", ["l2", "cosine"])
 @pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
 def test_dot_centrality_compiles(compiled_kernels, one_chip, metric,
@@ -110,12 +146,33 @@ def test_topk_smallest_compiles(compiled_kernels, one_chip):
     _compile(lambda t: ops.kernel_topk_smallest(t, keep=C // 2), theta)
 
 
+def _key(sharding):
+    return _spec((), jax.eval_shape(lambda: jax.random.key(0)).dtype,
+                 sharding)
+
+
 def test_l1_fused_medoid_program_compiles(compiled_kernels, one_chip):
     n, d = 4096, 512
     fn = programs.medoid_program(budget=30 * n, metric="l1",
                                  backend="pallas_fused")
     data = _spec((n, d), jnp.float32, one_chip)
-    key = _spec((), jax.eval_shape(lambda: jax.random.key(0)).dtype,
-                one_chip)
-    compiled = fn.lower(data, key).compile()
+    compiled = fn.lower(data, _key(one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "kernel_centrality_sums_at" in compiled.as_text()
+
+
+def test_l1_fused_batch_and_ragged_programs_compile(compiled_kernels,
+                                                    one_chip):
+    """The vmapped programs: the indexed kernel over stacked queries."""
+    b, n, d = 2, 1024, 512
+    data = _spec((b, n, d), jnp.float32, one_chip)
+    batch = programs.batch_program(budget=30 * n, metric="l1",
+                                   backend="pallas_fused")
+    ragged = programs.ragged_program(n_bucket=n, budget=30 * n, metric="l1",
+                                     backend="pallas_fused_topk")
+    lengths = _spec((b,), jnp.int32, one_chip)
+    for text in (batch.lower(data, _key(one_chip)).compile().as_text(),
+                 ragged.lower(data, lengths, _key(one_chip)).compile()
+                 .as_text()):
+        assert "tpu_custom_call" in text
+        assert "kernel_centrality_sums_at" in text

@@ -80,8 +80,11 @@ def test_normed_tally_matches_a_hand_count(metric, backend, normed):
                         backend=backend, budget_per_arm=30)
     work = d.work("medoid")
     assert work.normed == 2 * normed
+    # the Gram metrics gather the rows they norm; l1 on the fused backend
+    # fetches its rows by index and gathers none
+    assert work.gathered == 2 * normed
     counters = instrument.work_counters()
-    assert set(counters) == {"called", "computed", "normed"}
+    assert set(counters) == {"called", "computed", "normed", "gathered"}
 
 
 def test_normed_tally_counts_every_query_of_a_batch():

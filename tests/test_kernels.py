@@ -6,6 +6,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels import pairwise_distance as pk
 
 
 def _data(c, r, d, dtype, seed=0):
@@ -101,6 +102,157 @@ def test_l1_centrality_sums_accuracy_against_float64():
     x64, y64 = np.asarray(x, np.float64), np.asarray(y, np.float64)
     want = np.abs(x64[:, None, :] - y64[None]).sum(axis=(1, 2))
     assert np.max(np.abs(got - want) / want) <= 1.3134e-7
+
+
+# ------------- the ℓ1 kernel that fetches its rows by index ----------------
+
+def _corpus(n, d, seed):
+    """A corpus whose rows hold exact zeros, as count data does."""
+    k = jax.random.key(seed)
+    x = jax.random.uniform(jax.random.fold_in(k, 1), (n, d))
+    return jnp.where(jax.random.bernoulli(jax.random.fold_in(k, 2), 0.3,
+                                          (n, d)), 0.0, x)
+
+
+def _draws(n, c, r, seed):
+    """Candidate and reference indices drawn with replacement (so rows
+    repeat), and reference weights with zeros among them."""
+    k = jax.random.key(seed)
+    ci = jax.random.randint(jax.random.fold_in(k, 3), (c,), 0, n)
+    ri = jax.random.randint(jax.random.fold_in(k, 4), (r,), 0, n)
+    w = jax.random.bernoulli(jax.random.fold_in(k, 5), 0.7, (r,))
+    return ci, ri, w.astype(jnp.float32)
+
+
+# widths off the lane multiple, row counts off the sublane multiple, and
+# both extreme tilings: many candidates against few references, and few
+# against many (several reference tiles, several width blocks)
+@pytest.mark.parametrize("n,d,c,r", [
+    (60, 300, 70, 13), (37, 1000, 9, 45), (300, 129, 300, 8),
+    (700, 300, 5, 1100), (50, 1000, 3, 520)])
+def test_l1_centrality_at_is_bitwise_the_gathered_kernel(n, d, c, r):
+    data = _corpus(n, d, seed=n + d)
+    ci, ri, w = _draws(n, c, r, seed=c * 7 + r)
+    rows = ops.l1_rows(data)
+    for mask in (None, w):
+        want = ops.kernel_centrality_sums(data[ci], data[ri], metric="l1",
+                                          ref_mask=mask)
+        got = ops.kernel_centrality_sums_at(rows, ci, ri, width=d,
+                                            ref_mask=mask)
+        assert got.shape == (c,)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("n,d,r", [(37, 300, 13), (300, 129, 8),
+                                   (130, 1000, 70)])
+def test_l1_centrality_at_every_row_in_storage_order(n, d, r):
+    """The first band's call: every row a candidate, read from the corpus
+    as it lies (its partial last tile and lanes past d included)."""
+    data = _corpus(n, d, seed=n + r)
+    _, ri, w = _draws(n, 1, r, seed=r)
+    want = ops.kernel_centrality_sums(data, data[ri], metric="l1",
+                                      ref_mask=w)
+    got = ops.kernel_centrality_sums_at(ops.l1_rows(data), None, ri,
+                                        width=d, ref_mask=w, corpus=data)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    batch = jnp.stack([data, data[::-1]])
+    got = jax.vmap(lambda x: ops.kernel_centrality_sums_at(
+        ops.l1_rows(x), None, ri, width=d, ref_mask=w, corpus=x))(batch)
+    for x, g in zip(batch, got):
+        want = ops.kernel_centrality_sums(x, x[ri], metric="l1", ref_mask=w)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(want))
+
+
+def test_l1_centrality_at_under_vmap():
+    """The batched and ragged programs vmap the kernel: each query its own
+    corpus, draws and weights, or one corpus shared by every query."""
+    b, n, d = 3, 40, 300
+    data = jnp.stack([_corpus(n, d, seed=s) for s in range(b)])
+    draws = [_draws(n, 11, 30, seed=s) for s in range(b)]
+    ci, ri, w = (jnp.stack(a) for a in zip(*draws))
+    want = jax.vmap(lambda x, c, r, m: ops.kernel_centrality_sums(
+        x[c], x[r], metric="l1", ref_mask=m))(data, ci, ri, w)
+    got = jax.vmap(lambda x, c, r, m: ops.kernel_centrality_sums_at(
+        ops.l1_rows(x), c, r, width=d, ref_mask=m))(data, ci, ri, w)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    rows = ops.l1_rows(data[0])
+    shared = jax.vmap(lambda c, r, m: ops.kernel_centrality_sums_at(
+        rows, c, r, width=d, ref_mask=m))(ci, ri, w)
+    want = jax.vmap(lambda c, r, m: ops.kernel_centrality_sums(
+        data[0][c], data[0][r], metric="l1", ref_mask=m))(ci, ri, w)
+    np.testing.assert_array_equal(np.asarray(shared), np.asarray(want))
+
+
+@pytest.mark.parametrize("budget", [3000, 700])
+def test_l1_centrality_at_splits_what_smem_cannot_hold(monkeypatch, budget):
+    """Index vectors beyond the kernel's SMEM go in several kernel calls:
+    two whole queries per call (3000 bytes), or one query's candidates in
+    pieces (700); the sums stay bitwise those of the gathered kernel."""
+    b, n, d, c, r = 3, 50, 130, 300, 20
+    data = jnp.stack([_corpus(n, d, seed=40 + s) for s in range(b)])
+    draws = [_draws(n, c, r, seed=50 + s) for s in range(b)]
+    ci, ri, w = (jnp.stack(a) for a in zip(*draws))
+    want = jax.vmap(lambda x, cc, rr, m: ops.kernel_centrality_sums(
+        x[cc], x[rr], metric="l1", ref_mask=m))(data, ci, ri, w)
+    monkeypatch.setattr(pk, "L1_INDEX_BYTES", budget)
+    jax.clear_caches()
+    got = jax.vmap(lambda x, cc, rr, m: ops.kernel_centrality_sums_at(
+        ops.l1_rows(x), cc, rr, width=d, ref_mask=m))(data, ci, ri, w)
+    jax.clear_caches()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not ops.l1_index_fits(n)
+
+
+def test_l1_centrality_at_accuracy_against_float64():
+    """The float64 check of the gathered kernel, with the rows named by
+    index into one corpus: within the same relative error."""
+    k = jax.random.key(16)
+    x = jax.random.uniform(jax.random.fold_in(k, 1), (2, 1024))
+    y = jax.random.uniform(jax.random.fold_in(k, 2), (4096, 1024))
+    data = jnp.concatenate([x, y])
+    got = np.asarray(ops.kernel_centrality_sums_at(
+        ops.l1_rows(data), jnp.arange(2), jnp.arange(2, 4098), width=1024),
+        np.float64)
+    x64, y64 = np.asarray(x, np.float64), np.asarray(y, np.float64)
+    want = np.abs(x64[:, None, :] - y64[None]).sum(axis=(1, 2))
+    assert np.max(np.abs(got - want) / want) <= 1.3134e-7
+
+
+@pytest.mark.parametrize("n,d", [(37, 300), (16, 256), (5, 1000), (33, 1)])
+def test_l1_rows_is_the_zero_padded_chunk_view(n, d):
+    data = _corpus(n, d, seed=n * d)
+    nch = pk.l1_row_chunks(d)
+    want = jnp.pad(data, ((0, 0), (0, nch * 128 - d))).reshape(n * nch, 128)
+    np.testing.assert_array_equal(np.asarray(ops.l1_rows(data)),
+                                  np.asarray(want))
+
+
+def test_l1_tile_fits_whole_rows_and_the_indexed_kernel_knows_its_limit():
+    """The indexed kernel's candidate block leaves room for whole rows in
+    VMEM: the gathered kernel's at RNA-Seq width, fewer rows where rows are
+    wider, and no indexed kernel where not even 8 fit (those calls gather,
+    as before). The gathered kernel's block never shrinks for it."""
+    assert pk.l1_row_chunks(27998) == 224
+    for indexed in (False, True):
+        assert pk.l1_centrality_tile(20000, 8, 27998, indexed=indexed) \
+            == (128, 8, 7040)
+        assert pk.l1_centrality_tile(40, 4000, 27998, indexed=indexed) \
+            == (40, 504, 1408)
+    for d in (60000, 150000):
+        bc = pk.l1_centrality_tile(20000, 8, d, indexed=True)[0]
+        assert bc < 128
+        assert 4 * 128 * pk.l1_row_chunks(d) \
+            * (2 * bc + pk.L1_REF_SLOTS * 8) <= pk.L1_ROW_BYTES
+        assert pk.l1_centrality_tile(20000, 8, d)[0] == 128
+    # past the indexed kernel's widths the gathered calls keep their blocks
+    assert pk.l1_rows_cap(200000) == 0
+    assert pk.l1_centrality_tile(313, 506, 200000) == (112, 512, 1664)
+    assert pk.l1_centrality_tile(20000, 8, 200000) == (128, 8, 7424)
+    wide = jax.ShapeDtypeStruct((10, 300000), jnp.float32)
+    assert pk.l1_rows_cap(300000) == 0
+    assert ops.centrality_kernel_at("l1")(wide) is None
+    assert all(ops.centrality_kernel_at(m) is None
+               for m in ("l2", "sql2", "cosine"))
 
 
 @pytest.mark.parametrize("metric", ["l2", "sql2", "cosine"])
